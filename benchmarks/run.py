@@ -22,6 +22,19 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
+def _cpu_child_env() -> dict:
+    """Environment of a forced-host-device child: the repo's ``src`` on
+    its path and JAX held to the CPU, so it never contends for a chip
+    that this (parent) process may hold."""
+    env = dict(os.environ)
+    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                           "src"))
+    old_pp = env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = src_dir + (os.pathsep + old_pp if old_pp else "")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def _time(fn, reps=3, warmup=1):
     for _ in range(warmup):
         fn()
@@ -99,17 +112,18 @@ def bench_table12_memory(rows, quick=False):
 
 
 def bench_kernels(rows, quick=False):
-    """Pallas kernels vs jnp reference, same op on both sides (CPU: the
-    kernels run in the Pallas interpreter, so their wall time is a
-    validation-mode number; 'derived' reports the oracle error)."""
+    """Pallas kernels vs jnp reference, same op on both sides, through the
+    ``kernels/ops`` wrappers (off-TPU the kernels run in the Pallas
+    interpreter, so their wall time is a validation-mode number and the
+    row name says ``_interpret``; 'derived' reports the oracle error)."""
     import jax
     import jax.numpy as jnp
     from repro.core import expansions as ex
+    from repro.kernels import ops as kops
     from repro.kernels import ref
-    from repro.kernels.m2l import m2l_pallas
-    from repro.kernels.p2p import p2p_pallas
-    from repro.kernels.flash_attn import flash_attention
+    from repro.kernels.p2p import P2P_HALO
 
+    mode = "_interpret" if kops._interpret() else ""
     rng = np.random.default_rng(0)
     ny = nx = 8 if quick else 16
     s = 8
@@ -117,14 +131,20 @@ def bench_kernels(rows, quick=False):
                     jnp.complex64)
     q = jnp.asarray(rng.normal(size=(ny, nx, s)) + 0j, jnp.complex64)
     mask = jnp.ones((ny, nx, s), bool)
+    halo = ((P2P_HALO, P2P_HALO), (P2P_HALO, P2P_HALO), (0, 0))
+
+    def p2p_kernel():
+        return kops.p2p_apply_slab(jnp.pad(z, halo), jnp.pad(q, halo),
+                                   jnp.pad(mask, halo), 0.05)
+
     expect = np.asarray(ref.p2p_ref(z, q, mask, 0.05))
     p2p_jit = jax.jit(lambda a, b, c: ref.p2p_ref(a, b, c, 0.05))
     p2p_ref_t = _time(lambda: jax.block_until_ready(p2p_jit(z, q, mask)))
-    err = float(np.linalg.norm(np.asarray(p2p_pallas(z, q, mask, 0.05)) - expect) /
+    err = float(np.linalg.norm(np.asarray(p2p_kernel()) - expect) /
                 np.linalg.norm(expect))
     rows.append(("kernel_p2p_ref_jnp", p2p_ref_t, f"pallas_relerr={err:.1e}"))
-    p2p_k_t = _time(lambda: jax.block_until_ready(p2p_pallas(z, q, mask, 0.05)))
-    rows.append(("kernel_p2p_pallas_interpret", p2p_k_t,
+    p2p_k_t = _time(lambda: jax.block_until_ready(p2p_kernel()))
+    rows.append((f"kernel_p2p_pallas{mode}", p2p_k_t,
                  f"same_op_ref_us={p2p_ref_t:.1f}"))
 
     p = 17
@@ -134,11 +154,11 @@ def bench_kernels(rows, quick=False):
     expect = np.asarray(ref.m2l_ref(me, level, p))          # masked-40 oracle
     m2l_fold = jax.jit(lambda g: ex.m2l_reference(g, level, p))
     m2l_t = _time(lambda: jax.block_until_ready(m2l_fold(me)))
-    err = float(np.linalg.norm(np.asarray(m2l_pallas(me, level, p)) - expect) /
+    err = float(np.linalg.norm(np.asarray(kops.m2l_apply(me, level, p)) - expect) /
                 np.linalg.norm(expect))
     rows.append(("kernel_m2l_ref_jnp", m2l_t, f"pallas_relerr={err:.1e}"))
-    m2l_k_t = _time(lambda: jax.block_until_ready(m2l_pallas(me, level, p)))
-    rows.append(("kernel_m2l_pallas_interpret", m2l_k_t,
+    m2l_k_t = _time(lambda: jax.block_until_ready(kops.m2l_apply(me, level, p)))
+    rows.append((f"kernel_m2l_pallas{mode}", m2l_k_t,
                  f"same_op_ref_us={m2l_t:.1f}"))
 
     qq = jnp.asarray(rng.normal(size=(1, 4, 128, 64)), jnp.float32)
@@ -146,8 +166,8 @@ def bench_kernels(rows, quick=False):
     expect = np.asarray(ref.attention_ref(qq, kk, kk))
     fa_t = _time(lambda: jax.block_until_ready(ref.attention_ref(qq, kk, kk)))
     err = float(np.linalg.norm(
-        np.asarray(flash_attention(qq, kk, kk, block_q=64, block_k=64)) - expect) /
-        np.linalg.norm(expect))
+        np.asarray(kops.flash_attention(qq, kk, kk, block_q=64, block_k=64))
+        - expect) / np.linalg.norm(expect))
     rows.append(("kernel_flash_attn_ref_jnp", fa_t, f"pallas_relerr={err:.1e}"))
 
 
@@ -212,10 +232,7 @@ def bench_parallel_multidevice(rows, quick=False):
             fn()
         print("US", (time.perf_counter() - t0) / 3 * 1e6)
     """)
-    env = dict(os.environ)
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
-    old_pp = env.get("PYTHONPATH", "")
-    env["PYTHONPATH"] = src_dir + (os.pathsep + old_pp if old_pp else "")
+    env = _cpu_child_env()
     try:
         proc = subprocess.run([sys.executable, "-c", body], capture_output=True,
                               text=True, env=env, timeout=600)
@@ -259,10 +276,7 @@ def bench_plan_execution(rows, quick=False):
     ndev = 4
     m_side, p, steps = (120, 8, 3) if quick else (160, 12, 4)
     groups = (("uniform",), ("model", "dynamic"), ("block",), ("auto",))
-    env = dict(os.environ)
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
-    old_pp = env.get("PYTHONPATH", "")
-    env["PYTHONPATH"] = src_dir + (os.pathsep + old_pp if old_pp else "")
+    env = _cpu_child_env()
     for group in groups:
         body = textwrap.dedent(f"""
             import os
@@ -415,7 +429,6 @@ def bench_overlap(rows, quick=False):
                     pf._tile_halo(q, 1, rv, cv, "data", grid),
                     pf._tile_halo(m, 1, rv, cv, "data", grid))
         spec = P("data", None, None)
-        kw = {{pf._CHECK_KW: False}} if pf._CHECK_KW else {{}}
         rng = np.random.default_rng(0)
         s = tree.slots
         shape = ({ndev} * rmax, cmax, s)
@@ -428,8 +441,8 @@ def bench_overlap(rows, quick=False):
         stats = {{}}
         lows = {{}}
         for name, fn in (("fused", fused), ("unfused", unfused)):
-            jfn = jax.jit(pf._shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
-                                        out_specs=(spec,) * 3, **kw))
+            jfn = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                                        out_specs=(spec,) * 3, check_vma=False))
             lows[name] = C.Lowered(jfn, z, q, m, label=name)
             nperm = cc.measure(lows[name].text(cc.ir))
             jax.block_until_ready(jfn(z, q, m))
@@ -447,10 +460,7 @@ def bench_overlap(rows, quick=False):
         print(f"ROW p2p_exchange_fused {{fus:.1f}} {{tag}}"
               f"collectives={{nf}}_was={{nu}}_unfused_us={{unf:.1f}}")
     """)
-    env = dict(os.environ)
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
-    old_pp = env.get("PYTHONPATH", "")
-    env["PYTHONPATH"] = src_dir + (os.pathsep + old_pp if old_pp else "")
+    env = _cpu_child_env()
     names = ("overlap_on", "overlap_off", "p2p_exchange_fused")
     try:
         proc = subprocess.run([sys.executable, "-c", body], capture_output=True,
@@ -558,11 +568,7 @@ def bench_pipeline(rows, quick=False):
               f"gather_issue_depth={{ag_on}}_was={{ag_off}}"
               f"_permutes={{np_on}}_was={{np_off}}")
     """)
-    env = dict(os.environ)
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-    old_pp = env.get("PYTHONPATH", "")
-    env["PYTHONPATH"] = src_dir + (os.pathsep + old_pp if old_pp else "")
+    env = _cpu_child_env()
     names = ("pipeline_on", "pipeline_off", "gather_overlap")
     try:
         proc = subprocess.run([sys.executable, "-c", body],
@@ -628,11 +634,7 @@ def bench_guarded_step(rows, quick=False):
         print(f"ROW guarded_step_overhead {{gu:.1f}} {{tag}}"
               f"ratio={{ratio:.3f}}_unguarded_us={{un:.1f}}")
     """)
-    env = dict(os.environ)
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-    old_pp = env.get("PYTHONPATH", "")
-    env["PYTHONPATH"] = src_dir + (os.pathsep + old_pp if old_pp else "")
+    env = _cpu_child_env()
     try:
         proc = subprocess.run([sys.executable, "-c", body],
                               capture_output=True, text=True, env=env,
@@ -909,11 +911,7 @@ def bench_serve(rows, quick=False):
               f"_session_p50={{s['p50_ms']:.0f}}ms_p99="
               f"{{s['p99_ms']:.0f}}ms")
     """)
-    env = dict(os.environ)
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-    old_pp = env.get("PYTHONPATH", "")
-    env["PYTHONPATH"] = src_dir + (os.pathsep + old_pp if old_pp else "")
+    env = _cpu_child_env()
     names = ("serve_batched", "serve_sequential", "serve_throughput",
              "serve_latency")
     try:
@@ -986,6 +984,8 @@ def main() -> None:
         if i + 1 >= len(sys.argv) or sys.argv[i + 1].startswith("--"):
             sys.exit("usage: python -m benchmarks.run [--quick] [--json PATH]")
         json_path = sys.argv[i + 1]
+    from repro.configs.backend import use_compile_cache
+    use_compile_cache()
     rows: list[tuple[str, float, str]] = []
     for bench in (bench_fig6_stage_timings, bench_fig7_9_scaling,
                   bench_table12_memory, bench_kernels, bench_m2l_staging_bytes,
@@ -1006,6 +1006,10 @@ def main() -> None:
             json.dump([{"name": n, "us_per_call": round(u, 1), "derived": d}
                        for n, u, d in rows], f, indent=2)
         print(f"# wrote {json_path}", file=sys.stderr)
+    failed = [name for name, _, derived in rows
+              if derived.startswith("failed:")]
+    if failed:
+        sys.exit(f"failed rows: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

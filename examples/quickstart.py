@@ -14,6 +14,7 @@ import numpy as np
 
 sys.path.insert(0, "src")
 
+from repro.configs.backend import use_compile_cache
 from repro.core.fmm import fmm_velocity
 from repro.core.quadtree import build_tree, choose_level, gather_particle_values
 from repro.core.vortex import direct_sum, lamb_oseen_particles, lamb_oseen_velocity
@@ -24,8 +25,10 @@ def main():
     ap.add_argument("--n-side", type=int, default=120)
     ap.add_argument("--p", type=int, default=17)
     ap.add_argument("--use-kernels", action="store_true",
-                    help="route M2L/P2P through the Pallas kernels (interpret)")
+                    help="route M2L/P2P through the Pallas kernels "
+                         "(interpreted off-TPU)")
     args = ap.parse_args()
+    use_compile_cache()
 
     pos, gamma, sigma = lamb_oseen_particles(args.n_side)
     n = len(pos)

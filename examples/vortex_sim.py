@@ -94,6 +94,7 @@ def main():
 
     sys.path.insert(0, "src")
     from repro.configs import backend
+    backend.use_compile_cache()
     if args.debug_nans:
         # debug-NaN wants the raw failure, not a recovered one
         backend.set_debug_nan(True)

@@ -90,10 +90,9 @@ def _fused_exchange(grid, ndev):
         return pf._unpack_particles(buf, z.dtype)
 
     spec = P("data", None, None)
-    kw = {pf._CHECK_KW: False} if pf._CHECK_KW else {}
-    jfn = jax.jit(pf._shard_map(fused, mesh=_mesh(ndev),
+    jfn = jax.jit(jax.shard_map(fused, mesh=_mesh(ndev),
                                 in_specs=(spec,) * 3,
-                                out_specs=(spec,) * 3, **kw))
+                                out_specs=(spec,) * 3, check_vma=False))
     shape = (ndev * rmax, cmax, 2)
     z = jnp.ones(shape, jnp.complex64)
     return jfn, (z, z, jnp.ones(shape, bool))

@@ -12,6 +12,32 @@ import os
 import warnings
 from multiprocessing import cpu_count
 
+# <repo>/src/repro/configs/backend.py -> <repo>
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives for this checkout.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins when it is set (JAX reads it
+    itself); otherwise the fixed ``<repo>/.jax_cache`` — a fixed path,
+    because the directory is part of the cache key."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_REPO_ROOT, ".jax_cache"))
+
+
+def use_compile_cache() -> str:
+    """Turn on the persistent compilation cache; returns its directory.
+
+    Sets no directory in code when ``JAX_COMPILATION_CACHE_DIR`` is set.
+    Call at the start of a driver, before its first compile."""
+    import jax
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 def jax_enable_x64(use_x64: bool) -> None:
     """Switch the default array precision to 64-bit (or back to 32)."""

@@ -46,6 +46,11 @@ from .quadtree import (M2L_OFFSETS, M2L_VALIDITY, PARENT_NEIGH8, box_size)
 # c_parent) / r_parent = ((cx - .5)/2, (cy - .5)/2).
 CHILD_OFFSETS = [(cy, cx) for cy in range(2) for cx in range(2)]
 
+# The translation contractions run in full f32: at default precision a
+# TPU does f32 dots in one bf16 pass, which the p = 17 error bound can't
+# absorb.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _binom_table(n: int) -> np.ndarray:
     c = np.zeros((n, n), dtype=np.float64)
@@ -114,13 +119,16 @@ def m2l_operator(p: int) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _powers(zhat: jnp.ndarray, p: int) -> jnp.ndarray:
-    """Stack [zhat^0, ..., zhat^(p-1)] along a new last axis."""
-    ones = jnp.ones_like(zhat)
-    steps = [ones]
-    for _ in range(p - 1):
-        steps.append(steps[-1] * zhat)
-    return jnp.stack(steps, axis=-1)
+def _zhat(z: jnp.ndarray, mask: jnp.ndarray, centers: jnp.ndarray,
+          r: float) -> jnp.ndarray:
+    """Slot offsets from the box center in box units, 0 in empty slots.
+
+    An empty slot holds ``z = 0``, up to ``2**level`` box widths from its
+    center; its ``p - 1``-th power overflows f32 from level 8 at p = 17,
+    and a masked zero times ``inf`` is NaN.  Live slots sit inside their
+    box, so ``|zhat| <= 1/sqrt(2)`` there.
+    """
+    return jnp.where(mask, (z - centers[..., None]) / r, 0.0)
 
 
 def p2m(z: jnp.ndarray, q: jnp.ndarray, mask: jnp.ndarray, centers: jnp.ndarray,
@@ -130,11 +138,19 @@ def p2m(z: jnp.ndarray, q: jnp.ndarray, mask: jnp.ndarray, centers: jnp.ndarray,
     ``coeff`` is an optional (p,) per-order charge map ``c_k`` (the
     equation spec's ``p2m_coeff``): ``ahat_k = c_k sum q zhat^k``.  None
     is the identity map of the velocity kernel.
+
+    The moments accumulate one power at a time in f32 elementwise
+    arithmetic: no ``(n, n, s, p)`` power stack (on a TPU each
+    ``(n, n, s)`` plane is lane-padded to 128 slots) and no MXU
+    contraction (bf16 passes at default precision).
     """
-    zhat = (z - centers[..., None]) / r            # (n, n, s)
-    pw = _powers(zhat, p)                          # (n, n, s, p)
-    qm = jnp.where(mask, q, 0.0)
-    me = jnp.einsum("yxs,yxsk->yxk", qm, pw)
+    zhat = _zhat(z, mask, centers, r)              # (n, n, s)
+    term = jnp.where(mask, q, 0.0)                 # q zhat^k, k = 0
+    moments = []
+    for _ in range(p):
+        moments.append(term.sum(axis=-1))
+        term = term * zhat
+    me = jnp.stack(moments, axis=-1)
     if coeff is not None:
         me = me * jnp.asarray(coeff, dtype=me.dtype)
     return me
@@ -154,7 +170,7 @@ def m2m(me_child: jnp.ndarray, p: int, op: np.ndarray | None = None
     c = me_child.reshape(ny, 2, nx, 2, p)          # [py, cy, px, cx, k]
     # CHILD_OFFSETS order is (cy, cx) row-major -> index c = cy*2+cx
     c = c.transpose(0, 2, 1, 3, 4).reshape(ny, nx, 4, p)
-    return jnp.einsum("yxck,cmk->yxm", c, op)
+    return jnp.einsum("yxck,cmk->yxm", c, op, precision=_HIGHEST)
 
 
 def parity_mask(n: int, validity_o: np.ndarray) -> np.ndarray:
@@ -334,7 +350,8 @@ def m2l_folded(me_halo: jnp.ndarray, level: int, p: int, row0: int = 0,
     for d, (Dx, Dy) in enumerate(PARENT_NEIGH8):
         src = stack[1 + Dy:1 + Dy + PR, 1 + Dx:1 + Dx + PC, :]
         acc = acc + jnp.einsum("yxa,ab->yxb", src,
-                               jnp.asarray(W[d], dtype=me_halo.dtype))
+                               jnp.asarray(W[d], dtype=me_halo.dtype),
+                               precision=_HIGHEST)
     le = from_parent_planes(acc, p)                        # (2PR, 2PC, p)
     le = jax.lax.slice_in_dim(le, rshift, rshift + rows, axis=0)
     le = jax.lax.slice_in_dim(le, cshift, cshift + cols, axis=1)
@@ -351,41 +368,45 @@ def l2l(le_parent: jnp.ndarray, p: int) -> jnp.ndarray:
     """Parent grid (ny, nx, p) -> child grid (2ny, 2nx, p)."""
     op = jnp.asarray(l2l_operator(p), dtype=le_parent.dtype)
     ny, nx = le_parent.shape[0], le_parent.shape[1]
-    c = jnp.einsum("yxl,cml->yxcm", le_parent, op)  # (ny, nx, 4, m)
+    c = jnp.einsum("yxl,cml->yxcm", le_parent, op,
+                   precision=_HIGHEST)                 # (ny, nx, 4, m)
     c = c.reshape(ny, nx, 2, 2, p).transpose(0, 2, 1, 3, 4)
     return c.reshape(2 * ny, 2 * nx, p)
 
 
-def l2p(le: jnp.ndarray, z: jnp.ndarray, centers: jnp.ndarray, r: float,
-        p: int) -> jnp.ndarray:
-    """Evaluate leaf LEs at particle positions -> complex W, (n, n, s)."""
-    zhat = (z - centers[..., None]) / r
-    pw = _powers(zhat, p)                          # (n, n, s, p)
-    return jnp.einsum("yxl,yxsl->yxs", le, pw)
-
-
-def l2p_eval(le: jnp.ndarray, z: jnp.ndarray, centers: jnp.ndarray, r: float,
-             p: int, modes: tuple[str, ...] = ("value",)) -> jnp.ndarray:
+def l2p_eval(le: jnp.ndarray, z: jnp.ndarray, mask: jnp.ndarray,
+             centers: jnp.ndarray, r: float, p: int,
+             modes: tuple[str, ...] = ("value",)) -> jnp.ndarray:
     """Evaluate leaf LEs at (source or target) positions, per channel.
+
+    ``mask`` marks the occupied slots; empty slots are evaluated at their
+    box center, so their output is finite (and masked by the caller).
 
     ``modes`` is the equation spec's ``l2p_modes``; each entry emits one
     complex channel: ``"value"`` is the LE polynomial itself (the velocity
     for the vortex kernel, the complex potential for Laplace) and
     ``"ngrad"`` its negated z-derivative ``-(1/r) sum_l l bhat_l
     zhat^(l-1)`` (the Laplace field).  Returns (n, n, s) for one mode,
-    (n, n, s, len(modes)) otherwise; single-mode output is bit-identical
-    to :func:`l2p`.
+    (n, n, s, len(modes)) otherwise.
+
+    Each polynomial is evaluated by Horner's rule in f32 elementwise
+    arithmetic over ``(n, n, s)`` planes (see :func:`p2m` for why).
     """
-    zhat = (z - centers[..., None]) / r
-    pw = _powers(zhat, p)                          # (n, n, s, p)
+    zhat = _zhat(z, mask, centers, r)              # (n, n, s)
+
+    def horner(coef):                              # sum_l coef_l zhat^l
+        acc = jnp.broadcast_to(coef[..., -1:], zhat.shape)
+        for l in range(coef.shape[-1] - 2, -1, -1):
+            acc = acc * zhat + coef[..., l:l + 1]
+        return acc
+
     outs = []
     for mode in modes:
         if mode == "value":
-            outs.append(jnp.einsum("yxl,yxsl->yxs", le, pw))
+            outs.append(horner(le))
         elif mode == "ngrad":
             lw = jnp.arange(1, p, dtype=le.real.dtype)
-            outs.append(-jnp.einsum("yxl,yxsl->yxs", le[..., 1:] * lw,
-                                    pw[..., :p - 1]) / r)
+            outs.append(-horner(le[..., 1:] * lw) / r)
         else:
             raise ValueError(f"unknown l2p mode {mode!r}")
     return outs[0] if len(outs) == 1 else jnp.stack(outs, axis=-1)

@@ -344,7 +344,8 @@ def fmm_evaluate(tree: Tree, p: int, eq=None, use_kernels: bool = False,
     le = downward_sweep(me, p, m2l_fn=m2l_fn)
     centers = jnp.asarray(box_centers(L), dtype=tree.z.dtype)
     z_eval = tree.z if targets is None else targets.z
-    far = ex.l2p_eval(le[L], z_eval, centers, box_size(L), p, eq.l2p_modes)
+    far = ex.l2p_eval(le[L], z_eval, out_mask, centers, box_size(L), p,
+                      eq.l2p_modes)
     near = near_field(tree, p2p_fn=p2p, z_tgt=zt)
     out = _mask_channels(out_mask, far + near)
     if not with_health:

@@ -84,19 +84,6 @@ from . import health as hw
 from .plan import BlockPlan, SlabPlan, uniform_plan
 from .quadtree import Tree, box_centers, box_size
 
-# jax >= 0.6 exposes shard_map at the top level; older versions under
-# jax.experimental.  Resolve once, version-compatibly — including the name
-# of the replication-check kwarg (check_rep, renamed check_vma in jax 0.7).
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_CHECK_KW = next((k for k in ("check_rep", "check_vma")
-                  if k in _inspect.signature(_shard_map).parameters), None)
-
-
 def _tile_halo(x: jnp.ndarray, width: int, rows_valid, cols_valid,
                axis_name: str, grid: tuple[int, int]) -> jnp.ndarray:
     """Attach ±``width`` ghost rows AND columns at the *valid* tile edges.
@@ -374,8 +361,8 @@ def _parallel_fmm_body(z, q, mask, *extra, plan: BlockPlan, l_cut: int,
     le_leaf = le_prev if L > l_cut else slice_tile(le_rep[L], 0)
 
     # ---- evaluation -------------------------------------------------------
-    z_eval = z if zt is None else zt
-    far = ex.l2p_eval(le_leaf, z_eval, my_centers, box_size(L), p,
+    z_eval, m_eval = (z, mask) if zt is None else (zt, mt)
+    far = ex.l2p_eval(le_leaf, z_eval, m_eval, my_centers, box_size(L), p,
                       eq.l2p_modes)
     if overlap:
         near = fmm.p2p_tile_overlapped(p2p_slab, z, q, mask,
@@ -384,7 +371,7 @@ def _parallel_fmm_body(z, q, mask, *extra, plan: BlockPlan, l_cut: int,
     else:
         near = p2p_slab(z_buf, q_buf, m_buf, sigma, zt)
     # padded rows/cols (mask=False) are dropped here
-    out = fmm._mask_channels(mask if mt is None else mt, far + near)
+    out = fmm._mask_channels(m_eval, far + near)
     out = _faults.corrupt_tile(out, faults, di)
     if not with_health:
         return out
@@ -395,8 +382,7 @@ def _parallel_fmm_body(z, q, mask, *extra, plan: BlockPlan, l_cut: int,
     health = hw.with_flag(health, hw.F_COEFF,
                           jnp.maximum(hw.nonfinite(me[L]),
                                       hw.nonfinite(le_leaf)))
-    health = hw.with_flag(health, hw.F_VEL,
-                          hw.nonfinite(out, mask if mt is None else mt))
+    health = hw.with_flag(health, hw.F_VEL, hw.nonfinite(out, m_eval))
     return out, health
 
 
@@ -508,11 +494,10 @@ def parallel_fmm_evaluate(tree: Tree, p: int, mesh: Optional[Mesh] = None,
         out_spec = (out_spec, P(mesh_axis))
     # pallas_call has no shard_map replication rule; disable the check on
     # the kernel route (numerics are unaffected — outputs stay sharded).
-    kwargs = {_CHECK_KW: False} if (use_kernels and _CHECK_KW) else {}
     pre_spec = (P(mesh_axis, None, None, None),) * len(pre)
-    fn = _shard_map(body, mesh=mesh,
-                    in_specs=(spec,) * (3 + len(t_sh)) + pre_spec,
-                    out_specs=out_spec, **kwargs)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(spec,) * (3 + len(t_sh)) + pre_spec,
+                       out_specs=out_spec, check_vma=not use_kernels)
     if with_health:
         w, h = fn(z_sh, q_sh, m_sh, *t_sh, *pre)
         health = hw.device_combine(h.reshape(P_, hw.N_FIELDS))
@@ -583,8 +568,8 @@ def parallel_fmm_p2p_prefetch(tree: Tree, mesh: Optional[Mesh] = None,
                           my_rows, my_cols, mesh_axis, (Pr, Pc))
 
     spec = P(mesh_axis, None, None)
-    fn = _shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
-                    out_specs=P(mesh_axis, None, None, None))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+                       out_specs=P(mesh_axis, None, None, None))
     return fn(z_sh, q_sh, m_sh)
 
 

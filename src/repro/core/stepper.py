@@ -473,6 +473,14 @@ class VortexStepper:
             self.payload = jax.tree_util.tree_map(scatter, payload_values)
         else:
             self.payload = None
+        if self.mesh is not None:
+            # the jitted step returns its state replicated over the mesh;
+            # place the host-built state the same way, or the second step
+            # compiles the step again for the new input sharding
+            rep = jax.sharding.NamedSharding(self.mesh,
+                                             jax.sharding.PartitionSpec())
+            self.tree, self.payload = jax.device_put((self.tree,
+                                                      self.payload), rep)
         cut = self._cut if self._cut is not None else min(level - 1, 4)
         self.params = ModelParams(level=level, cut=max(cut, 1), p=self.p,
                                   slots=slots)

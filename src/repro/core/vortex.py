@@ -12,6 +12,7 @@ both kernels are ``q/(z - z_j)`` times a mollifier.
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 
@@ -32,7 +33,8 @@ def pairwise_w(z_tgt: jnp.ndarray, z_src: jnp.ndarray, q_src: jnp.ndarray,
     if sigma is not None:
         moll = 1.0 - jnp.exp(-r2 / (2.0 * sigma * sigma))
         inv = inv * moll.astype(inv.dtype)
-    return jnp.einsum("...ts,...s->...t", inv, q_src)
+    return jnp.einsum("...ts,...s->...t", inv, q_src,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def direct_sum(z: np.ndarray, gamma: np.ndarray, sigma: float | None,

@@ -75,8 +75,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
                    static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jnp.ndarray:
-    """q: (B, H, T, d); k, v: (B, Hkv, S, d) with H % Hkv == 0 -> (B, H, T, d)."""
+                    block_k: int = 128,
+                    interpret: bool | None = None) -> jnp.ndarray:
+    """q: (B, H, T, d); k, v: (B, Hkv, S, d) with H % Hkv == 0 -> (B, H, T, d).
+
+    ``interpret=None`` runs the Pallas interpreter only off-TPU."""
+    if interpret is None:
+        from .ops import _interpret
+        interpret = _interpret()
     B, H, T, d = q.shape
     _, Hkv, S, _ = k.shape
     assert H % Hkv == 0

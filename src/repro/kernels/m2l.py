@@ -12,9 +12,10 @@ neither:
     ±1 parent halo (= 2 child rows; see DESIGN.md §4).  Same bytes as the
     grid itself, no 40x staging tensor;
   * the Pallas grid tiles the parent grid into ``(BY, BX)`` blocks whose
-    BlockSpecs read **overlapping halo tiles** ``(BY+2, BX+2, 4p)`` directly
-    from the padded parent-plane grid (``pl.Unblocked`` element-offset
-    indexing), so the halo never exists as a separate HBM buffer;
+    BlockSpecs read **overlapping halo windows** directly from the padded
+    parent-plane grid (``pl.Element`` offsets in the TPU-legal form of
+    ``kernels/halo.py``), so the halo never exists as a separate HBM
+    buffer;
   * the parity-folded ``(8, 4p, 4p)`` block operator (scale-normalized,
     hence level-independent — DESIGN.md §3) is VMEM-resident across the
     whole launch; its structural zero blocks *are* the parity masks, so
@@ -38,11 +39,18 @@ from jax.experimental import pallas as pl
 from ..core import equations as _eqs
 from ..core import expansions as ex
 from ..core.quadtree import PARENT_NEIGH8
+from .halo import LANES, halo_tiling, round_up
+
+
+def _dot(a, b):
+    # full f32 on the MXU (a default-precision f32 dot is one bf16 pass)
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def _m2l_kernel(sr_ref, si_ref, wr_ref, wi_ref, or_ref, oi_ref,
                 *, BY: int, BX: int, p4: int):
-    tr = sr_ref[...]            # (BY+2, BX+2, 4p) halo tile, real
+    tr = sr_ref[...]            # (BY+2, >= BX+2, 4p) halo window, real
     ti = si_ref[...]
     wr = wr_ref[...]            # (8, 4p, 4p) folded operator, VMEM-resident
     wi = wi_ref[...]
@@ -52,10 +60,8 @@ def _m2l_kernel(sr_ref, si_ref, wr_ref, wi_ref, or_ref, oi_ref,
         ar = tr[1 + Dy:1 + Dy + BY, 1 + Dx:1 + Dx + BX, :].reshape(BY * BX, p4)
         ai = ti[1 + Dy:1 + Dy + BY, 1 + Dx:1 + Dx + BX, :].reshape(BY * BX, p4)
         # complex GEMM via 4 real GEMMs (MXU); accumulator stays in VMEM
-        accr = accr + jnp.dot(ar, wr[d], preferred_element_type=jnp.float32) \
-            - jnp.dot(ai, wi[d], preferred_element_type=jnp.float32)
-        acci = acci + jnp.dot(ar, wi[d], preferred_element_type=jnp.float32) \
-            + jnp.dot(ai, wr[d], preferred_element_type=jnp.float32)
+        accr = accr + _dot(ar, wr[d]) - _dot(ai, wi[d])
+        acci = acci + _dot(ar, wi[d]) + _dot(ai, wr[d])
     or_ref[...] = accr.reshape(BY, BX, p4)
     oi_ref[...] = acci.reshape(BY, BX, p4)
 
@@ -66,7 +72,7 @@ def _m2l_kernel(sr_ref, si_ref, wr_ref, wi_ref, or_ref, oi_ref,
 def m2l_pallas_slab(me_halo: jnp.ndarray, level: int, p: int, row0: int = 0,
                     halo: int = ex.M2L_HALO, col0: int = 0, col_halo: int = 0,
                     block: tuple[int, int] = (8, 8),
-                    interpret: bool = True,
+                    interpret: bool | None = None,
                     lane_pad: bool = False, eq=None) -> jnp.ndarray:
     """Parity-folded M2L over a halo'd slab/tile — same contract as
     ``expansions.m2l_folded``: ``me_halo`` is (rows + 2*halo,
@@ -82,39 +88,39 @@ def m2l_pallas_slab(me_halo: jnp.ndarray, level: int, p: int, row0: int = 0,
     ``eq`` selects the equation spec supplying the folded block operator
     and dimension scalar (core/equations.py; vortex default) — the kernel
     body is equation-independent: one contraction, any registered operator.
+    ``interpret=None`` runs the Pallas interpreter only off-TPU
+    (``kernels.ops._interpret``).
     """
+    if interpret is None:
+        from .ops import _interpret
+        interpret = _interpret()
     eq = _eqs.get_equation(eq)
     rows = me_halo.shape[0] - 2 * halo
     cols = me_halo.shape[1] - 2 * col_halo
     p4 = 4 * p
-    p4l = -(-p4 // 128) * 128 if lane_pad else p4
+    p4l = round_up(p4, LANES) if lane_pad else p4
     stack, (PR, shift), (PC, cshift) = ex.m2l_slab_stack(me_halo, p, row0,
                                                          halo, col0, col_halo)
 
-    BY, BX = min(block[0], PR), min(block[1], PC)
-    PRp = -(-PR // BY) * BY
-    PCp = -(-PC // BX) * BX
-    sr = jnp.pad(stack.real.astype(jnp.float32),
-                 ((0, PRp - PR), (0, PCp - PC), (0, p4l - p4)))
-    si = jnp.pad(stack.imag.astype(jnp.float32),
-                 ((0, PRp - PR), (0, PCp - PC), (0, p4l - p4)))
+    t = halo_tiling(PR, PC, block)
+    pad = ((0, t.rows_p - PR), (0, t.halo_cols - (PC + 2)), (0, p4l - p4))
+    sr = jnp.pad(stack.real.astype(jnp.float32), pad)
+    si = jnp.pad(stack.imag.astype(jnp.float32), pad)
 
     W = eq.m2l_folded(p, level)
     wpad = ((0, 0), (0, p4l - p4), (0, p4l - p4))
     wr = jnp.asarray(np.pad(W.real, wpad), dtype=jnp.float32)
     wi = jnp.asarray(np.pad(W.imag, wpad), dtype=jnp.float32)
 
-    grid = (PRp // BY, PCp // BX)
-    halo_spec = pl.BlockSpec((BY + 2, BX + 2, p4l),
-                             lambda i, j: (i * BY, j * BX, 0),
-                             indexing_mode=pl.Unblocked())
+    halo_spec = t.window_spec(p4l)
     op_spec = pl.BlockSpec((8, p4l, p4l), lambda i, j: (0, 0, 0))
-    out_spec = pl.BlockSpec((BY, BX, p4l), lambda i, j: (i, j, 0))
-    out_shape = [jax.ShapeDtypeStruct((PRp, PCp, p4l), jnp.float32)] * 2
+    out_spec = t.block_spec(p4l)
+    out_shape = [jax.ShapeDtypeStruct((t.rows_p, t.cols_p, p4l),
+                                      jnp.float32)] * 2
 
     br, bi = pl.pallas_call(
-        functools.partial(_m2l_kernel, BY=BY, BX=BX, p4=p4l),
-        grid=grid,
+        functools.partial(_m2l_kernel, BY=t.BY, BX=t.BX, p4=p4l),
+        grid=t.grid,
         in_specs=[halo_spec, halo_spec, op_spec, op_spec],
         out_specs=[out_spec, out_spec],
         out_shape=out_shape,
@@ -130,7 +136,7 @@ def m2l_pallas_slab(me_halo: jnp.ndarray, level: int, p: int, row0: int = 0,
 
 def m2l_pallas(me: jnp.ndarray, level: int, p: int,
                block: tuple[int, int] = (8, 8),
-               interpret: bool = True, lane_pad: bool = False,
+               interpret: bool | None = None, lane_pad: bool = False,
                eq=None) -> jnp.ndarray:
     """Fused M2L over a full (ny, nx, p) complex ME grid -> (ny, nx, p) LE."""
     me_halo = jnp.pad(me, ((ex.M2L_HALO, ex.M2L_HALO), (0, 0), (0, 0)))

@@ -1,8 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the Pallas interpreter executes the
-kernel body on CPU for validation); on TPU backends the compiled kernels
-run natively.
+Every kernel entry point takes ``interpret=None``, which ``_interpret``
+resolves: the Pallas interpreter executes the kernel body off-TPU (CPU
+validation), and on a TPU the compiled kernels always run.
 
 The M2L/P2P wrappers come in two forms with one kernel behind both: the
 grid form (serial driver — zero ghosts attached here) and the slab form
@@ -49,14 +49,17 @@ def _interpret() -> bool:
 # execution plan actually launches: full interior/monolithic tiles, wide
 # row-slab tiles, and the thin rim strips of the overlapped driver (a few
 # rows/cols spanning the whole tile edge).  Values are clipped to the
-# launch extents, and the kernels pad non-dividing extents up to a block
-# multiple, so any entry is legal for any shape.
+# launch extents; the kernels round BX up to whole sublanes (8) and pad
+# non-dividing extents up to a block multiple (kernels/halo.py), and P2P
+# shrinks the block until its pair tensor fits (``p2p.pair_block``), so
+# any entry is legal for any shape.  Every BX here is a multiple of 8: a
+# multi-block halo window then reads BX + 8 columns for the BX + 2 it uses.
 BLOCK_TABLE: dict[str, tuple[int, int]] = {
     "rim_row": (2, 32),     # thin row strips: keep the whole strip in one
-    "rim_col": (32, 2),     # sublane/lane-friendly pass along its long axis
-    "small": (4, 4),        # tiles smaller than one default block
-    "wide": (8, 16),        # row-slab tiles much wider than tall
-    "tile": (8, 8),         # default square interior launch
+    "rim_col": (32, 8),     # pass along its long axis
+    "small": (4, 8),        # tiles smaller than one default block
+    "wide": (4, 64),        # row-slab tiles much wider than tall
+    "tile": (4, 32),        # default interior launch: 128-row M2L GEMMs
 }
 
 

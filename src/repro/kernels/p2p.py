@@ -9,8 +9,8 @@ nothing:
   * the leaf grid is padded by ±1 box (zeros at the domain edge; under
     ``shard_map`` the ghost rows have already been exchanged by the caller)
     and the Pallas grid tiles it into ``(BY, BX)`` blocks whose BlockSpecs
-    read **overlapping halo tiles** ``(BY+2, BX+2, s)`` directly from the
-    padded grid (``pl.Unblocked`` element-offset indexing);
+    read **overlapping halo windows** directly from the padded grid
+    (``pl.Element`` offsets in the TPU-legal form of ``kernels/halo.py``);
   * the kernel slices the 9 neighbor offsets out of its VMEM tile and
     evaluates the pair interaction on the VPU, keeping the accumulators in
     VMEM across the whole 9-offset reduction — one HBM write per output
@@ -25,9 +25,10 @@ nothing:
     source == target mode slices its targets out of the already-loaded
     halo tile (no extra input streams — the pre-registry data path).
 
-Block sizing: the (BY*BX, st, s) pair tensor should stay under ~2 MiB (f32),
-and the lane dimension (s) should be a multiple of 128 on real hardware (pad
-``s`` accordingly; correctness does not depend on it).
+Block sizing: the wrapper shrinks ``(BY, BX)`` until one offset's
+(BY*BX, st, s) f32 pair tensor fits ``PAIR_BYTES``, and the lane dimension
+(s) should be a multiple of 128 on real hardware (pad ``s`` accordingly;
+correctness does not depend on it).
 """
 from __future__ import annotations
 
@@ -38,9 +39,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core import equations as _eqs
-from ..core.quadtree import P2P_OFFSETS
+from .halo import LANES, SUBLANES, halo_tiling, round_up
 
 P2P_HALO = 1    # ghost rows/cols of particle data needed by a slab
+PAIR_BYTES = 1 << 19   # bound on one offset's (BY*BX, st, s) f32 pair tensor
+
+
+def pair_block(block: tuple[int, int], st: int, s: int) -> tuple[int, int]:
+    """Shrink ``block`` so one offset's pair tensor fits ``PAIR_BYTES``:
+    ``BX`` down to one sublane tile first, then ``BY``."""
+    boxes = max(PAIR_BYTES // (4 * st * s), 1)
+    bx = min(block[1], max(boxes, SUBLANES))
+    by = max(min(block[0], boxes // round_up(bx, SUBLANES)), 1)
+    return by, bx
 
 
 def _p2p_kernel(*refs, eq, sigma: float | None, BY: int, BX: int,
@@ -50,40 +61,46 @@ def _p2p_kernel(*refs, eq, sigma: float | None, BY: int, BX: int,
     else:
         txr_ref, txi_ref, zr_ref, zi_ref, qr_ref, qi_ref, m_ref, \
             *out_refs = refs
-    zr = zr_ref[...]            # (BY+2, BX+2, s) source halo tiles
-    zi = zi_ref[...]
-    qr = qr_ref[...]
-    qi = qi_ref[...]
-    m = m_ref[...]
+    BB = BY * BX
     if tgt_from_src:
-        # source == target mode: the targets ARE the halo tile's interior
-        # — slice them out of the already-loaded zr/zi instead of paying
-        # two extra HBM->VMEM input streams (st == s here)
-        tx = zr[1:1 + BY, 1:1 + BX, :].reshape(BY * BX, st)
-        ty = zi[1:1 + BY, 1:1 + BX, :].reshape(BY * BX, st)
+        # source == target mode: the targets ARE the halo window's interior
+        # — read them out of the source windows instead of paying two extra
+        # HBM->VMEM input streams (st == s here)
+        tx = zr_ref[1:1 + BY, 1:1 + BX, :].reshape(BB, st)
+        ty = zi_ref[1:1 + BY, 1:1 + BX, :].reshape(BB, st)
     else:
-        tx = txr_ref[...].reshape(BY * BX, st)   # (BY, BX, st) target block
-        ty = txi_ref[...].reshape(BY * BX, st)
+        tx = txr_ref[...].reshape(BB, st)        # (BY, BX, st) target block
+        ty = txi_ref[...].reshape(BB, st)
     nout = len(out_refs) // 2
-    accs = [jnp.zeros((BY * BX, st), jnp.float32) for _ in range(2 * nout)]
-    for (dx, dy) in P2P_OFFSETS:
-        sx = zr[1 + dy:1 + dy + BY, 1 + dx:1 + dx + BX, :].reshape(BY * BX, s)
-        sy = zi[1 + dy:1 + dy + BY, 1 + dx:1 + dx + BX, :].reshape(BY * BX, s)
-        sqr = qr[1 + dy:1 + dy + BY, 1 + dx:1 + dx + BX, :].reshape(BY * BX, s)
-        sqi = qi[1 + dy:1 + dy + BY, 1 + dx:1 + dx + BX, :].reshape(BY * BX, s)
-        sm = m[1 + dy:1 + dy + BY, 1 + dx:1 + dx + BX, :].reshape(BY * BX, s)
-        ddx = tx[:, :, None] - sx[:, None, :]            # (BB, st, s)
-        ddy = ty[:, :, None] - sy[:, None, :]
-        r2 = ddx * ddx + ddy * ddy
-        valid = (sm[:, None, :] > 0) & (r2 > 0.0)
-        moll = None
-        if sigma is not None:
-            moll = 1.0 - jnp.exp(-r2 / (2.0 * sigma * sigma))
-        terms = eq.p2p_terms(ddx, ddy, r2, valid, sqr[:, None, :],
-                             sqi[:, None, :], moll)
-        for c, (tre, tim) in enumerate(terms):
-            accs[2 * c] = accs[2 * c] + tre.sum(axis=-1)
-            accs[2 * c + 1] = accs[2 * c + 1] + tim.sum(axis=-1)
+
+    def offset_row(r, accs):
+        # the three P2P_OFFSETS of source row dy = r - 1, in their order;
+        # a loop over rows keeps the kernel body (and its compile) 3x
+        # smaller than unrolling all nine offsets
+        accs = list(accs)
+        rows = pl.ds(r, BY)
+        for dx in range(3):
+            def src(ref):
+                return ref[rows, dx:dx + BX, :].reshape(BB, s)
+            sx, sy, sqr, sqi, sm = map(src, (zr_ref, zi_ref, qr_ref, qi_ref,
+                                             m_ref))
+            ddx = tx[:, :, None] - sx[:, None, :]        # (BB, st, s)
+            ddy = ty[:, :, None] - sy[:, None, :]
+            r2 = ddx * ddx + ddy * ddy
+            valid = (sm[:, None, :] > 0) & (r2 > 0.0)
+            moll = None
+            if sigma is not None:
+                moll = 1.0 - jnp.exp(-r2 / (2.0 * sigma * sigma))
+            terms = eq.p2p_terms(ddx, ddy, r2, valid, sqr[:, None, :],
+                                 sqi[:, None, :], moll)
+            for c, (tre, tim) in enumerate(terms):
+                accs[2 * c] = accs[2 * c] + tre.sum(axis=-1)
+                accs[2 * c + 1] = accs[2 * c + 1] + tim.sum(axis=-1)
+        return tuple(accs)
+
+    accs = jax.lax.fori_loop(
+        0, 3, offset_row,
+        tuple(jnp.zeros((BB, st), jnp.float32) for _ in range(2 * nout)))
     for i, ref in enumerate(out_refs):
         ref[...] = accs[i].reshape(BY, BX, st)
 
@@ -91,7 +108,8 @@ def _p2p_kernel(*refs, eq, sigma: float | None, BY: int, BX: int,
 @functools.partial(jax.jit, static_argnames=("sigma", "block", "interpret",
                                              "lane_pad", "eq"))
 def p2p_pallas_slab(z_halo, q_halo, mask_halo, sigma=None,
-                    block: tuple[int, int] = (8, 8), interpret: bool = True,
+                    block: tuple[int, int] = (8, 8),
+                    interpret: bool | None = None,
                     lane_pad: bool = False, z_tgt=None, eq=None):
     """P2P over a slab with ±1 ghost rows/cols already attached.
 
@@ -106,53 +124,51 @@ def p2p_pallas_slab(z_halo, q_halo, mask_halo, sigma=None,
     ``lane_pad=True`` pads the slot axes up to lane multiples of 128
     (real-TPU layout; DESIGN.md §5) — padded source slots carry ``mask=0``
     so they are structurally excluded and the numerics are unchanged; the
-    output is sliced back to ``st``.
+    output is sliced back to ``st``.  ``interpret=None`` runs the Pallas
+    interpreter only off-TPU (``kernels.ops._interpret``).
     """
+    if interpret is None:
+        from .ops import _interpret
+        interpret = _interpret()
     eq = _eqs.get_equation(eq)
     rows, cols, s = (z_halo.shape[0] - 2, z_halo.shape[1] - 2,
                      z_halo.shape[2])
     tgt_from_src = z_tgt is None
     st = s if tgt_from_src else z_tgt.shape[2]
-    sl = -(-s // 128) * 128 if lane_pad else s
-    stl = sl if tgt_from_src else (-(-st // 128) * 128 if lane_pad else st)
-    BY, BX = min(block[0], rows), min(block[1], cols)
-    rowsP = -(-rows // BY) * BY
-    colsP = -(-cols // BX) * BX
+    sl = round_up(s, LANES) if lane_pad else s
+    stl = sl if tgt_from_src else (round_up(st, LANES) if lane_pad else st)
+    t = halo_tiling(rows, cols, pair_block(block, stl, sl))
 
-    def prep(x, lanes):
-        # halo'd sources (rows+2 -> rowsP+2) and bare targets (rows ->
-        # rowsP) take the same trailing pad
+    def prep(x, lanes, cols_to):
         return jnp.pad(x.astype(jnp.float32),
-                       ((0, rowsP - rows), (0, colsP - cols),
+                       ((0, t.rows_p - rows), (0, cols_to - x.shape[1]),
                         (0, lanes - x.shape[2])))
 
-    zr, zi = prep(z_halo.real, sl), prep(z_halo.imag, sl)
-    qr, qi = prep(q_halo.real, sl), prep(q_halo.imag, sl)
-    m = prep(mask_halo, sl)
+    # halo'd sources (rows+2 -> rows_p+2 rows, halo_cols columns)
+    zr, zi, qr, qi, m = (prep(x, sl, t.halo_cols) for x in (
+        z_halo.real, z_halo.imag, q_halo.real, q_halo.imag, mask_halo))
 
-    grid = (rowsP // BY, colsP // BX)
-    halo_spec = pl.BlockSpec((BY + 2, BX + 2, sl),
-                             lambda i, j: (i * BY, j * BX, 0),
-                             indexing_mode=pl.Unblocked())
-    tgt_spec = pl.BlockSpec((BY, BX, stl), lambda i, j: (i, j, 0))
-    out_spec = pl.BlockSpec((BY, BX, stl), lambda i, j: (i, j, 0))
-    out_shape = [jax.ShapeDtypeStruct((rowsP, colsP, stl), jnp.float32)
-                 ] * (2 * eq.nout)
+    halo_spec = t.window_spec(sl)
+    tgt_spec = t.block_spec(stl)
+    out_shape = [jax.ShapeDtypeStruct((t.rows_p, t.cols_p, stl),
+                                      jnp.float32)] * (2 * eq.nout)
 
     if tgt_from_src:
         inputs = (zr, zi, qr, qi, m)
         in_specs = [halo_spec] * 5
     else:
-        txr, txi = prep(z_tgt.real, stl), prep(z_tgt.imag, stl)
+        # bare targets (rows -> rows_p, cols -> cols_p)
+        txr = prep(z_tgt.real, stl, t.cols_p)
+        txi = prep(z_tgt.imag, stl, t.cols_p)
         inputs = (txr, txi, zr, zi, qr, qi, m)
         in_specs = [tgt_spec, tgt_spec] + [halo_spec] * 5
 
     outs = pl.pallas_call(
-        functools.partial(_p2p_kernel, eq=eq, sigma=sigma, BY=BY, BX=BX,
+        functools.partial(_p2p_kernel, eq=eq, sigma=sigma, BY=t.BY, BX=t.BX,
                           st=stl, s=sl, tgt_from_src=tgt_from_src),
-        grid=grid,
+        grid=t.grid,
         in_specs=in_specs,
-        out_specs=[out_spec] * (2 * eq.nout),
+        out_specs=[tgt_spec] * (2 * eq.nout),
         out_shape=out_shape,
         interpret=interpret,
     )(*inputs)
@@ -164,12 +180,13 @@ def p2p_pallas_slab(z_halo, q_halo, mask_halo, sigma=None,
 
 
 def p2p_pallas(z, q, mask, sigma=None, block: tuple[int, int] = (8, 8),
-               interpret: bool = True, lane_pad: bool = False, eq=None):
+               interpret: bool | None = None, lane_pad: bool = False,
+               eq=None):
     """P2P over a (ny, nx, s) dense leaf grid.  Returns complex output per
     slot (trailing channel axis for multi-output equations).
 
-    z, q: complex64; mask: bool.  ``interpret=True`` runs the kernel body in
-    the Pallas interpreter (CPU validation); on TPU pass False.
+    z, q: complex64; mask: bool.  ``interpret=None`` runs the kernel body
+    in the Pallas interpreter off-TPU and compiles it on TPU.
     """
     pad = ((P2P_HALO, P2P_HALO), (P2P_HALO, P2P_HALO), (0, 0))
     return p2p_pallas_slab(jnp.pad(z, pad), jnp.pad(q, pad),

@@ -42,9 +42,15 @@ def main(argv=None):
     import jax
     from jax.sharding import Mesh
 
+    from ..configs.backend import use_compile_cache
     from ..serve import fmm_service as svc
 
-    ndev = min(args.devices, len(jax.devices()))
+    use_compile_cache()
+    ndev = args.devices
+    if len(jax.devices()) < ndev:
+        sys.exit(f"--devices {ndev} asks for more devices than the "
+                 f"{len(jax.devices())} {jax.default_backend()} device(s) "
+                 f"present")
     mesh = Mesh(np.array(jax.devices()[:ndev]), ("data",)) if ndev > 1 \
         else None
     engine = svc.FmmServiceEngine(

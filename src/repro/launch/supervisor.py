@@ -53,6 +53,7 @@ import sys
 import time
 from typing import Optional, Sequence
 
+from ..configs.backend import compile_cache_dir
 from ..parallel import resilience as rz
 
 _SRC_DIR = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -135,9 +136,8 @@ class Supervisor:
         # so one rank compiles and the rest (and later generations /
         # comparison runs) hit the cache — essential at 1-core CI.  An
         # inherited cache dir wins, so a test session can share one cache
-        # across drills.
-        env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                       os.path.join(self.cfg.coord_dir, "jaxcache"))
+        # across drills; otherwise the checkout's fixed cache directory.
+        env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
         env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
         pp = env.get("PYTHONPATH", "")
         if _SRC_DIR not in pp.split(os.pathsep):

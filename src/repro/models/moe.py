@@ -188,10 +188,15 @@ def moe_layer(p, x, cfg: ModelConfig, mesh: Optional[Mesh] = None,
 
     x_spec = P(dp_axes if dp_axes else None, None, None)
     e_spec = P("model", fsdp_ax, None)
+    # the layer shards by hand, so it runs on the mesh's devices with Auto
+    # axes: over jax.make_mesh's default Explicit axes, JAX refuses an eager
+    # call (and eager ops on its output) outside a jax.set_mesh context
+    mesh = Mesh(mesh.devices, mesh.axis_names)
     fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(x_spec, P(None, None), e_spec, e_spec, e_spec),
                        out_specs=x_spec)
-    return fn(x, p["router"], p["experts_gate"], p["experts_in"], p["experts_out"])
+    return fn(x, p["router"], p["experts_gate"], p["experts_in"],
+              p["experts_out"])
 
 
 def moe_param_specs(mesh: Mesh) -> dict:

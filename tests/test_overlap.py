@@ -160,7 +160,6 @@ _PACKED_BODY = textwrap.dedent("""
                 pf._tile_halo(m, 1, rv, cv, "data", grid))
 
     spec = P("data", None, None)
-    kw = {pf._CHECK_KW: False} if pf._CHECK_KW else {}
     rng = np.random.default_rng(0)
     shape = (4 * rmax, cmax, s)
     z = jnp.asarray(rng.normal(size=shape) + 1j * rng.normal(size=shape),
@@ -170,8 +169,8 @@ _PACKED_BODY = textwrap.dedent("""
     m = jnp.asarray(rng.uniform(size=shape) > 0.4)
     outs = {}
     for name, fn in (("fused", fused), ("unfused", unfused)):
-        sm = pf._shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
-                           out_specs=(spec,) * 3, **kw)
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                           out_specs=(spec,) * 3, check_vma=False)
         outs[name] = [np.asarray(a) for a in jax.jit(sm)(z, q, m)]
     for a, b in zip(outs["fused"], outs["unfused"]):
         assert a.dtype == b.dtype and np.array_equal(a, b)

@@ -182,14 +182,13 @@ _DEGENERATE_BODY = textwrap.dedent("""
     rmax = cmax = 8
     s = 3
     spec = P("data", None, None, None)
-    kw = {pf._CHECK_KW: False} if pf._CHECK_KW else {}
     rng = np.random.default_rng(0)
     packed = jnp.asarray(rng.normal(size=(4 * rmax, cmax, 5, s)), jnp.float32)
 
     def shapes_of(grid):
         fn = lambda x: pf._tile_halo(x, 1, rmax, cmax, "data", grid)
-        sm = pf._shard_map(fn, mesh=mesh, in_specs=(spec,),
-                           out_specs=spec, **kw)
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
+                           out_specs=spec, check_vma=False)
         text = jax.jit(sm).lower(packed).as_text()
         perm = [l for l in text.splitlines() if "collective_permute" in l]
         widths = set()
@@ -216,9 +215,9 @@ _DEGENERATE_BODY = textwrap.dedent("""
     # consumers index into; only the STRIPS shrank.  Interior of each
     # tile is the tile's own data, untouched, and the degenerate column
     # halo stays zero
-    out = np.asarray(jax.jit(pf._shard_map(
+    out = np.asarray(jax.jit(jax.shard_map(
         lambda x: pf._tile_halo(x, 1, rmax, cmax, "data", (4, 1)),
-        mesh=mesh, in_specs=(spec,), out_specs=spec, **kw))(packed))
+        mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False))(packed))
     assert out.shape == (4 * (rmax + 2), cmax + 2, 5, s)
     for d in range(4):
         r0 = d * (rmax + 2)
