@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from . import equations as eqs
 from . import expansions as ex
 from . import health as hw
+from .. import obs
 from .quadtree import P2P_OFFSETS, Tree, box_centers, box_size
 
 
@@ -246,13 +247,15 @@ def upward_sweep(tree: Tree, p: int, eq=None) -> list[jnp.ndarray]:
     """Build normalized MEs for every level; returns me[l] for l=0..L."""
     eq = eqs.get_equation(eq)
     L = tree.level
-    centers = jnp.asarray(box_centers(L), dtype=tree.z.dtype)
     me = [None] * (L + 1)
-    me[L] = ex.p2m(tree.z, tree.q, tree.mask, centers, box_size(L), p,
-                   coeff=eq.p2m_coeff(p))
+    with obs.scope("fmm.p2m"):
+        centers = jnp.asarray(box_centers(L), dtype=tree.z.dtype)
+        me[L] = ex.p2m(tree.z, tree.q, tree.mask, centers, box_size(L), p,
+                       coeff=eq.p2m_coeff(p))
     mop = eq.m2m_operator(p)
-    for l in range(L, 0, -1):
-        me[l - 1] = ex.m2m(me[l], p, op=mop)
+    with obs.scope("fmm.m2m"):
+        for l in range(L, 0, -1):
+            me[l - 1] = ex.m2m(me[l], p, op=mop)
     return me
 
 
@@ -268,9 +271,11 @@ def downward_sweep(me: list[jnp.ndarray], p: int,
     m2l = m2l_fn or m2l_grid_fn(p)
     le = [None] * (L + 1)
     for l in range(2, L + 1):
-        le[l] = m2l(me[l], l)
+        with obs.scope("fmm.m2l"):
+            le[l] = m2l(me[l], l)
         if l > 2:
-            le[l] = le[l] + ex.l2l(le[l - 1], p)
+            with obs.scope("fmm.l2l"):
+                le[l] = le[l] + ex.l2l(le[l - 1], p)
     return le
 
 
@@ -282,8 +287,9 @@ def near_field(tree: Tree, p2p_fn=None, z_tgt=None) -> jnp.ndarray:
     """
     slab = p2p_fn or p2p_slab_fn(use_kernels=False)
     pad = ((1, 1), (1, 1), (0, 0))
-    return slab(jnp.pad(tree.z, pad), jnp.pad(tree.q, pad),
-                jnp.pad(tree.mask, pad), tree.sigma, z_tgt)
+    with obs.scope("fmm.p2p"):
+        return slab(jnp.pad(tree.z, pad), jnp.pad(tree.q, pad),
+                    jnp.pad(tree.mask, pad), tree.sigma, z_tgt)
 
 
 def _mask_channels(mask, out):
@@ -331,30 +337,35 @@ def fmm_evaluate(tree: Tree, p: int, eq=None, use_kernels: bool = False,
     out_mask = tree.mask if targets is None else targets.mask
     if L < 2:
         # Tiny trees are all near field.
-        out = _mask_channels(out_mask, near_field(tree, p2p_fn=p2p,
-                                                  z_tgt=zt))
+        near = near_field(tree, p2p_fn=p2p, z_tgt=zt)
+        with obs.scope("fmm.assemble"):
+            out = _mask_channels(out_mask, near)
         if not with_health:
             return out
-        health = hw.with_flag(hw.empty(), hw.F_VEL,
-                              hw.nonfinite(out, out_mask))
+        with obs.scope("fmm.health"):
+            health = hw.with_flag(hw.empty(), hw.F_VEL,
+                                  hw.nonfinite(out, out_mask))
         return out, health
     m2l_fn = m2l_grid_fn(p, use_kernels, eq)
 
     me = upward_sweep(tree, p, eq)
     le = downward_sweep(me, p, m2l_fn=m2l_fn)
-    centers = jnp.asarray(box_centers(L), dtype=tree.z.dtype)
     z_eval = tree.z if targets is None else targets.z
-    far = ex.l2p_eval(le[L], z_eval, out_mask, centers, box_size(L), p,
-                      eq.l2p_modes)
+    with obs.scope("fmm.l2p"):
+        centers = jnp.asarray(box_centers(L), dtype=tree.z.dtype)
+        far = ex.l2p_eval(le[L], z_eval, out_mask, centers, box_size(L), p,
+                          eq.l2p_modes)
     near = near_field(tree, p2p_fn=p2p, z_tgt=zt)
-    out = _mask_channels(out_mask, far + near)
+    with obs.scope("fmm.assemble"):
+        out = _mask_channels(out_mask, far + near)
     if not with_health:
         return out
-    health = hw.empty()
-    health = hw.with_flag(health, hw.F_COEFF,
-                          jnp.maximum(hw.nonfinite(me[L]),
-                                      hw.nonfinite(le[L])))
-    health = hw.with_flag(health, hw.F_VEL, hw.nonfinite(out, out_mask))
+    with obs.scope("fmm.health"):
+        health = hw.empty()
+        health = hw.with_flag(health, hw.F_COEFF,
+                              jnp.maximum(hw.nonfinite(me[L]),
+                                          hw.nonfinite(le[L])))
+        health = hw.with_flag(health, hw.F_VEL, hw.nonfinite(out, out_mask))
     return out, health
 
 

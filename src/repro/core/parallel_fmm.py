@@ -81,6 +81,7 @@ from . import expansions as ex
 from . import faults as _faults
 from . import fmm
 from . import health as hw
+from .. import obs
 from .plan import BlockPlan, SlabPlan, uniform_plan
 from .quadtree import Tree, box_centers, box_size
 
@@ -237,16 +238,18 @@ def _parallel_fmm_body(z, q, mask, *extra, plan: BlockPlan, l_cut: int,
     p2p_slab = fmm.p2p_slab_fn(use_kernels, eq)
 
     # static per-device tile records, looked up by device index
-    di = jax.lax.axis_index(axis_name)
-    dev = np.arange(Pr * Pc)
-    my_row0 = jnp.asarray(np.asarray(plan.row0, np.int32)[dev // Pc])[di]
-    my_rows = jnp.asarray(np.asarray(plan.rows, np.int32)[dev // Pc])[di]
-    my_col0 = jnp.asarray(np.asarray(plan.col0, np.int32)[dev % Pc])[di]
-    my_cols = jnp.asarray(np.asarray(plan.cols, np.int32)[dev % Pc])[di]
+    with obs.scope("fmm.exchange"):
+        di = jax.lax.axis_index(axis_name)
+        dev = np.arange(Pr * Pc)
+        my_row0 = jnp.asarray(np.asarray(plan.row0, np.int32)[dev // Pc])[di]
+        my_rows = jnp.asarray(np.asarray(plan.rows, np.int32)[dev // Pc])[di]
+        my_col0 = jnp.asarray(np.asarray(plan.col0, np.int32)[dev % Pc])[di]
+        my_cols = jnp.asarray(np.asarray(plan.cols, np.int32)[dev % Pc])[di]
 
     def halo(x, width, rows_valid, cols_valid):
-        return _tile_halo(x, width, rows_valid, cols_valid, axis_name,
-                          (Pr, Pc))
+        with obs.scope("fmm.exchange"):
+            return _tile_halo(x, width, rows_valid, cols_valid, axis_name,
+                              (Pr, Pc))
 
     # ---- P2P halo: ONE packed exchange round (z, q, mask ride together) ---
     # Issued first under ``overlap`` so the collective is in flight through
@@ -255,29 +258,34 @@ def _parallel_fmm_body(z, q, mask, *extra, plan: BlockPlan, l_cut: int,
     # the Im q plane); targets are tile-local and exchange nothing.  A
     # prefetched buffer (the cross-substep double buffer, DESIGN.md §12)
     # replaces the exchange but not the fault/health plumbing downstream.
-    if p2p_pre is not None:
-        p2p_buf = p2p_pre
-    else:
-        p2p_buf = halo(_pack_particles(z, q, mask, eq.q_is_real), 1,
-                       my_rows, my_cols)
-    p2p_buf = _faults.corrupt_halo(p2p_buf, faults, di, (Pr, Pc))
-    halo_bad = hw.nonfinite(p2p_buf) if with_health else None
-    z_buf, q_buf, m_buf = _unpack_particles(p2p_buf, dtype, eq.q_is_real)
-
-    # centers padded below/right so the dynamic slice never clamps
-    centers = jnp.asarray(box_centers(L), dtype=dtype)
-    centers = jnp.pad(centers, ((0, rows_max), (0, cols_max)))
-    my_centers = jax.lax.dynamic_slice(centers, (my_row0, my_col0),
-                                       (rows_max, cols_max))
+    with obs.scope("fmm.exchange"):
+        if p2p_pre is not None:
+            p2p_buf = p2p_pre
+        else:
+            p2p_buf = halo(_pack_particles(z, q, mask, eq.q_is_real), 1,
+                           my_rows, my_cols)
+        p2p_buf = _faults.corrupt_halo(p2p_buf, faults, di, (Pr, Pc))
+    with obs.scope("fmm.health"):
+        halo_bad = hw.nonfinite(p2p_buf) if with_health else None
+    with obs.scope("fmm.exchange"):
+        z_buf, q_buf, m_buf = _unpack_particles(p2p_buf, dtype,
+                                                eq.q_is_real)
 
     # ---- upward sweep -----------------------------------------------------
     # Padding rows/cols have mask=False everywhere, so their MEs are exactly
     # zero and M2M keeps them zero at every coarser tile level.
+    with obs.scope("fmm.p2m"):
+        # centers padded below/right so the dynamic slice never clamps
+        centers = jnp.asarray(box_centers(L), dtype=dtype)
+        centers = jnp.pad(centers, ((0, rows_max), (0, cols_max)))
+        my_centers = jax.lax.dynamic_slice(centers, (my_row0, my_col0),
+                                           (rows_max, cols_max))
+        me = {L: ex.p2m(z, q, mask, my_centers, box_size(L), p,
+                        coeff=eq.p2m_coeff(p))}
     mop = eq.m2m_operator(p)
-    me = {L: ex.p2m(z, q, mask, my_centers, box_size(L), p,
-                    coeff=eq.p2m_coeff(p))}
-    for lv in range(L, l_cut, -1):
-        me[lv - 1] = ex.m2m(me[lv], p, op=mop)
+    with obs.scope("fmm.m2m"):
+        for lv in range(L, l_cut, -1):
+            me[lv - 1] = ex.m2m(me[lv], p, op=mop)
 
     # overlap: issue every sharded level's M2L exchange now, before the
     # root-tree gather/compute and the tile interiors that can hide them
@@ -288,25 +296,33 @@ def _parallel_fmm_body(z, q, mask, *extra, plan: BlockPlan, l_cut: int,
             me_bufs[lv] = halo(me[lv], ex.M2L_HALO, my_rows >> shift,
                                my_cols >> shift)
             if with_health:
-                halo_bad = jnp.maximum(halo_bad, hw.nonfinite(me_bufs[lv]))
+                with obs.scope("fmm.health"):
+                    halo_bad = jnp.maximum(halo_bad,
+                                           hw.nonfinite(me_bufs[lv]))
 
     # gather the cut level -> replicated root tree (paper's M2M to root);
     # unequal tiles are reassembled by the plan's static 2-D owner maps.
     cut_shift = L - l_cut
-    gathered = jax.lax.all_gather(me[l_cut], axis_name, axis=0, tiled=False)
+    with obs.scope("fmm.exchange"):
+        gathered = jax.lax.all_gather(me[l_cut], axis_name, axis=0,
+                                      tiled=False)
 
     def sharded_m2l(lv, bad):
         """One sharded level's M2L: interior+rim under ``overlap``, else the
         monolithic exchange-then-slab (local MEs only — no gather input)."""
         shift = L - lv
-        rv, cv = my_rows >> shift, my_cols >> shift
+        with obs.scope("fmm.exchange"):
+            rv, cv = my_rows >> shift, my_cols >> shift
         if overlap:
-            return fmm.m2l_tile_overlapped(m2l_slab, me[lv], me_bufs[lv],
-                                           lv, rv, cv), bad
+            with obs.scope("fmm.m2l"):
+                return fmm.m2l_tile_overlapped(m2l_slab, me[lv], me_bufs[lv],
+                                               lv, rv, cv), bad
         me_buf = halo(me[lv], ex.M2L_HALO, rv, cv)
         if with_health:
-            bad = jnp.maximum(bad, hw.nonfinite(me_buf))
-        return m2l_slab(me_buf, lv, col_halo=ex.M2L_HALO), bad
+            with obs.scope("fmm.health"):
+                bad = jnp.maximum(bad, hw.nonfinite(me_buf))
+        with obs.scope("fmm.m2l"):
+            return m2l_slab(me_buf, lv, col_halo=ex.M2L_HALO), bad
 
     # pipeline (DESIGN.md §12): consume NOTHING from the gather yet — every
     # sharded level's M2L reads only local MEs and the per-level exchanges,
@@ -319,19 +335,23 @@ def _parallel_fmm_body(z, q, mask, *extra, plan: BlockPlan, l_cut: int,
 
     # first consumption of the gathered buffer: the replicated root tree
     owner, loc_r, loc_c = plan.tile_maps(cut_shift)
-    me_cut_full = gathered[jnp.asarray(owner), jnp.asarray(loc_r),
-                           jnp.asarray(loc_c)]
+    with obs.scope("fmm.exchange"):
+        me_cut_full = gathered[jnp.asarray(owner), jnp.asarray(loc_r),
+                               jnp.asarray(loc_c)]
     me_rep = {l_cut: me_cut_full}
-    for lv in range(l_cut, 0, -1):
-        me_rep[lv - 1] = ex.m2m(me_rep[lv], p, op=mop)
+    with obs.scope("fmm.m2m"):
+        for lv in range(l_cut, 0, -1):
+            me_rep[lv - 1] = ex.m2m(me_rep[lv], p, op=mop)
 
     # ---- downward sweep ---------------------------------------------------
     # replicated root-tree levels 2 .. l_cut (same folded path, zero ghosts)
     le_rep: dict[int, jnp.ndarray] = {}
     for lv in range(2, l_cut + 1):
-        le_rep[lv] = m2l_grid(me_rep[lv], lv)
+        with obs.scope("fmm.m2l"):
+            le_rep[lv] = m2l_grid(me_rep[lv], lv)
         if lv > 2:
-            le_rep[lv] = le_rep[lv] + ex.l2l(le_rep[lv - 1], p)
+            with obs.scope("fmm.l2l"):
+                le_rep[lv] = le_rep[lv] + ex.l2l(le_rep[lv - 1], p)
 
     def slice_tile(grid_lv, shift):
         """My padded tile out of a replicated level grid."""
@@ -350,39 +370,46 @@ def _parallel_fmm_body(z, q, mask, *extra, plan: BlockPlan, l_cut: int,
     # 2-line halo suffices.
     le_prev = None  # my tile's LE at the previous (coarser) level
     if L > l_cut:
-        le_prev = slice_tile(le_rep[l_cut], cut_shift)
+        with obs.scope("fmm.l2l"):
+            le_prev = slice_tile(le_rep[l_cut], cut_shift)
     for lv in range(l_cut + 1, L + 1):
         if pipeline:
             le_lv = le_m2l[lv]
         else:
             le_lv, halo_bad = sharded_m2l(lv, halo_bad)
-        le_lv = le_lv + ex.l2l(le_prev, p)
+        with obs.scope("fmm.l2l"):
+            le_lv = le_lv + ex.l2l(le_prev, p)
         le_prev = le_lv
-    le_leaf = le_prev if L > l_cut else slice_tile(le_rep[L], 0)
+    with obs.scope("fmm.l2l"):
+        le_leaf = le_prev if L > l_cut else slice_tile(le_rep[L], 0)
 
     # ---- evaluation -------------------------------------------------------
     z_eval, m_eval = (z, mask) if zt is None else (zt, mt)
-    far = ex.l2p_eval(le_leaf, z_eval, m_eval, my_centers, box_size(L), p,
-                      eq.l2p_modes)
-    if overlap:
-        near = fmm.p2p_tile_overlapped(p2p_slab, z, q, mask,
-                                       z_buf, q_buf, m_buf,
-                                       my_rows, my_cols, sigma, z_tgt=zt)
-    else:
-        near = p2p_slab(z_buf, q_buf, m_buf, sigma, zt)
-    # padded rows/cols (mask=False) are dropped here
-    out = fmm._mask_channels(m_eval, far + near)
-    out = _faults.corrupt_tile(out, faults, di)
+    with obs.scope("fmm.l2p"):
+        far = ex.l2p_eval(le_leaf, z_eval, m_eval, my_centers, box_size(L),
+                          p, eq.l2p_modes)
+    with obs.scope("fmm.p2p"):
+        if overlap:
+            near = fmm.p2p_tile_overlapped(p2p_slab, z, q, mask,
+                                           z_buf, q_buf, m_buf,
+                                           my_rows, my_cols, sigma, z_tgt=zt)
+        else:
+            near = p2p_slab(z_buf, q_buf, m_buf, sigma, zt)
+    with obs.scope("fmm.assemble"):
+        # padded rows/cols (mask=False) are dropped here
+        out = fmm._mask_channels(m_eval, far + near)
+        out = _faults.corrupt_tile(out, faults, di)
     if not with_health:
         return out
     # per-device health word (flags only at driver level); the caller
     # reduces the stacked (P, N_FIELDS) output with the merge semantics
-    health = hw.empty()
-    health = hw.with_flag(health, hw.F_HALO, halo_bad)
-    health = hw.with_flag(health, hw.F_COEFF,
-                          jnp.maximum(hw.nonfinite(me[L]),
-                                      hw.nonfinite(le_leaf)))
-    health = hw.with_flag(health, hw.F_VEL, hw.nonfinite(out, m_eval))
+    with obs.scope("fmm.health"):
+        health = hw.empty()
+        health = hw.with_flag(health, hw.F_HALO, halo_bad)
+        health = hw.with_flag(health, hw.F_COEFF,
+                              jnp.maximum(hw.nonfinite(me[L]),
+                                          hw.nonfinite(le_leaf)))
+        health = hw.with_flag(health, hw.F_VEL, hw.nonfinite(out, m_eval))
     return out, health
 
 
@@ -465,15 +492,16 @@ def parallel_fmm_evaluate(tree: Tree, p: int, mesh: Optional[Mesh] = None,
         z_sh, q_sh, m_sh = tree.z, tree.q, tree.mask
         t_sh = () if targets is None else (targets.z, targets.mask)
     else:
-        src_r, src_c, valid = block.gather_index()
-        src_r, src_c = jnp.asarray(src_r), jnp.asarray(src_c)
-        v = jnp.asarray(valid)[:, :, None]
-        z_sh = jnp.where(v, tree.z[src_r, src_c], 0)
-        q_sh = jnp.where(v, tree.q[src_r, src_c], 0)
-        m_sh = tree.mask[src_r, src_c] & v
-        t_sh = () if targets is None else (
-            jnp.where(v, targets.z[src_r, src_c], 0),
-            targets.mask[src_r, src_c] & v)
+        with obs.scope("fmm.assemble"):
+            src_r, src_c, valid = block.gather_index()
+            src_r, src_c = jnp.asarray(src_r), jnp.asarray(src_c)
+            v = jnp.asarray(valid)[:, :, None]
+            z_sh = jnp.where(v, tree.z[src_r, src_c], 0)
+            q_sh = jnp.where(v, tree.q[src_r, src_c], 0)
+            m_sh = tree.mask[src_r, src_c] & v
+            t_sh = () if targets is None else (
+                jnp.where(v, targets.z[src_r, src_c], 0),
+                targets.mask[src_r, src_c] & v)
 
     l_cut = block.level - block.sharded_depth()
     pre = () if p2p_halo is None else (p2p_halo,)
@@ -500,12 +528,14 @@ def parallel_fmm_evaluate(tree: Tree, p: int, mesh: Optional[Mesh] = None,
                        out_specs=out_spec, check_vma=not use_kernels)
     if with_health:
         w, h = fn(z_sh, q_sh, m_sh, *t_sh, *pre)
-        health = hw.device_combine(h.reshape(P_, hw.N_FIELDS))
+        with obs.scope("fmm.health"):
+            health = hw.device_combine(h.reshape(P_, hw.N_FIELDS))
     else:
         w = fn(z_sh, q_sh, m_sh, *t_sh, *pre)
     if not identity:
-        sct_r, sct_c = block.scatter_index()
-        w = w[jnp.asarray(sct_r), jnp.asarray(sct_c)]
+        with obs.scope("fmm.assemble"):
+            sct_r, sct_c = block.scatter_index()
+            w = w[jnp.asarray(sct_r), jnp.asarray(sct_c)]
     return (w, health) if with_health else w
 
 
@@ -549,23 +579,27 @@ def parallel_fmm_p2p_prefetch(tree: Tree, mesh: Optional[Mesh] = None,
     if identity:
         z_sh, q_sh, m_sh = tree.z, tree.q, tree.mask
     else:
-        src_r, src_c, valid = block.gather_index()
-        src_r, src_c = jnp.asarray(src_r), jnp.asarray(src_c)
-        v = jnp.asarray(valid)[:, :, None]
-        z_sh = jnp.where(v, tree.z[src_r, src_c], 0)
-        q_sh = jnp.where(v, tree.q[src_r, src_c], 0)
-        m_sh = tree.mask[src_r, src_c] & v
+        with obs.scope("fmm.assemble"):
+            src_r, src_c, valid = block.gather_index()
+            src_r, src_c = jnp.asarray(src_r), jnp.asarray(src_c)
+            v = jnp.asarray(valid)[:, :, None]
+            z_sh = jnp.where(v, tree.z[src_r, src_c], 0)
+            q_sh = jnp.where(v, tree.q[src_r, src_c], 0)
+            m_sh = tree.mask[src_r, src_c] & v
     Pr, Pc = block.grid
 
     def body(z, q, m):
-        if eq.q_is_real:
-            q = (q.real + 0j).astype(z.dtype)
-        di = jax.lax.axis_index(mesh_axis)
-        dev = np.arange(Pr * Pc)
-        my_rows = jnp.asarray(np.asarray(block.rows, np.int32)[dev // Pc])[di]
-        my_cols = jnp.asarray(np.asarray(block.cols, np.int32)[dev % Pc])[di]
-        return _tile_halo(_pack_particles(z, q, m, eq.q_is_real), 1,
-                          my_rows, my_cols, mesh_axis, (Pr, Pc))
+        with obs.scope("fmm.exchange"):
+            if eq.q_is_real:
+                q = (q.real + 0j).astype(z.dtype)
+            di = jax.lax.axis_index(mesh_axis)
+            dev = np.arange(Pr * Pc)
+            my_rows = jnp.asarray(
+                np.asarray(block.rows, np.int32)[dev // Pc])[di]
+            my_cols = jnp.asarray(
+                np.asarray(block.cols, np.int32)[dev % Pc])[di]
+            return _tile_halo(_pack_particles(z, q, m, eq.q_is_real), 1,
+                              my_rows, my_cols, mesh_axis, (Pr, Pc))
 
     spec = P(mesh_axis, None, None)
     fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,

@@ -46,6 +46,7 @@ from . import faults as flt
 from . import health as hw
 from . import partition as pt
 from ..checkpoint.manager import CheckpointManager
+from .. import obs
 from .fmm import fmm_velocity
 from .parallel_fmm import parallel_fmm_p2p_prefetch, parallel_fmm_velocity
 from .plan import (BlockPlan, SlabPlan, assignment_from_plan, autotune_plan,
@@ -172,14 +173,18 @@ def rk2_step(tree: Tree, dt, payload=None, *, p: int, mesh=None,
     v1 = _velocity(tree, p, mesh, mesh_axis, use_kernels, plan, overlap,
                    with_health=guard, faults=faults, pipeline=pipeline)
     w1, h1 = v1 if guard else (v1, None)
-    z_mid = jnp.where(tree.mask, tree.z + 0.5 * dt * jnp.conj(w1), tree.z)
-    z_mid = flt.corrupt_positions(z_mid, tree.mask, faults)
-    live0 = tree.mask.sum()
-    ood1 = None
-    if guard and not pipeline:
-        ood1 = hw.out_of_domain_count(z_mid, tree.mask)
+    with obs.scope("stepper.kick"):
+        z_mid = jnp.where(tree.mask, tree.z + 0.5 * dt * jnp.conj(w1),
+                          tree.z)
+        z_mid = flt.corrupt_positions(z_mid, tree.mask, faults)
+    with obs.scope("stepper.health"):
+        live0 = tree.mask.sum()
+        ood1 = None
+        if guard and not pipeline:
+            ood1 = hw.out_of_domain_count(z_mid, tree.mask)
     aux = (tree.z, payload) if payload is not None else (tree.z,)
-    t_mid, aux, ok1 = rebuild_tree(tree, z_mid, aux=aux)
+    with obs.scope("stepper.rebin"):
+        t_mid, aux, ok1 = rebuild_tree(tree, z_mid, aux=aux)
     z0 = aux[0]
 
     # cross-substep double buffer (DESIGN.md §12): issue substep 2's packed
@@ -195,28 +200,34 @@ def rk2_step(tree: Tree, dt, payload=None, *, p: int, mesh=None,
         p2p_pre = parallel_fmm_p2p_prefetch(t_mid, mesh=mesh,
                                             mesh_axis=mesh_axis, plan=plan)
     if guard and pipeline:
-        ood1 = hw.out_of_domain_count(z_mid, tree.mask)
+        with obs.scope("stepper.health"):
+            ood1 = hw.out_of_domain_count(z_mid, tree.mask)
 
     v2 = _velocity(t_mid, p, mesh, mesh_axis, use_kernels, plan, overlap,
                    with_health=guard, faults=faults, pipeline=pipeline,
                    p2p_halo=p2p_pre)
     w2, h2 = v2 if guard else (v2, None)
-    z_new = jnp.where(t_mid.mask, z0 + dt * jnp.conj(w2), t_mid.z)
-    ood2 = hw.out_of_domain_count(z_new, t_mid.mask) if guard else None
-    t_new, aux, ok2 = rebuild_tree(t_mid, z_new,
-                                   aux=aux[1] if payload is not None else None)
-    occ = t_new.mask.sum(axis=-1).max()
-    health = None
-    if guard:
-        health = hw.merge(h1, h2)
-        health = hw.with_count(health, hw.F_OOD, ood1 + ood2)
-        # a rebin drop is live particles lost to capacity overflow — the
-        # count callers would silently lose if they ignored ``ok``
-        health = hw.with_count(health, hw.F_DROPPED,
-                               live0 - t_new.mask.sum())
-        health = hw.with_flag(health, hw.F_OVERFLOW, ~(ok1 & ok2))
-        health = hw.with_flag(health, hw.F_OCC, occ)
-    return t_new, aux, ok1 & ok2, occ, health
+    with obs.scope("stepper.kick"):
+        z_new = jnp.where(t_mid.mask, z0 + dt * jnp.conj(w2), t_mid.z)
+    with obs.scope("stepper.health"):
+        ood2 = hw.out_of_domain_count(z_new, t_mid.mask) if guard else None
+    with obs.scope("stepper.rebin"):
+        t_new, aux, ok2 = rebuild_tree(
+            t_mid, z_new, aux=aux[1] if payload is not None else None)
+    with obs.scope("stepper.health"):
+        occ = t_new.mask.sum(axis=-1).max()
+        health = None
+        if guard:
+            health = hw.merge(h1, h2)
+            health = hw.with_count(health, hw.F_OOD, ood1 + ood2)
+            # a rebin drop is live particles lost to capacity overflow —
+            # the count callers would silently lose if they ignored ``ok``
+            health = hw.with_count(health, hw.F_DROPPED,
+                                   live0 - t_new.mask.sum())
+            health = hw.with_flag(health, hw.F_OVERFLOW, ~(ok1 & ok2))
+            health = hw.with_flag(health, hw.F_OCC, occ)
+        ok = ok1 & ok2
+    return t_new, aux, ok, occ, health
 
 
 # Named jitted entry point for the static-analysis layer (repro/analysis):
@@ -498,7 +509,8 @@ class VortexStepper:
                                      self.params)["load_balance"]
 
     def counts(self) -> np.ndarray:
-        return np.asarray(self.tree.mask.sum(axis=-1))
+        with obs.span("stepper.pull"):
+            return np.asarray(self.tree.mask.sum(axis=-1))
 
     def particles(self) -> tuple[np.ndarray, np.ndarray]:
         """(positions, gamma) of the live particles, host-side, PHYSICAL
@@ -520,9 +532,10 @@ class VortexStepper:
 
     def _relevel(self):
         """Host rebuild at a freshly chosen level/capacity (overflow guard)."""
-        pos, gamma = self.particles()
-        self._build_host(pos, gamma,
-                         payload_values=self._gather_payload_values())
+        with obs.span("stepper.relevel"):
+            pos, gamma = self.particles()
+            self._build_host(pos, gamma,
+                             payload_values=self._gather_payload_values())
 
     def _expand_domain(self, margin: Optional[float] = None):
         """Grow the root box and rebuild — the recovery rung for particles
@@ -685,7 +698,8 @@ class VortexStepper:
         flags (both adoptions retrace on the NEXT step, which
         :func:`clean_wall_samples` relies on)."""
         if occ is None:
-            occ = int(np.asarray(self.tree.mask.sum(axis=-1).max()))
+            with obs.span("stepper.pull"):
+                occ = int(np.asarray(self.tree.mask.sum(axis=-1).max()))
         if occ >= self.occupancy_guard * self.params.slots:
             self._relevel()
             return "relevel"
@@ -776,22 +790,32 @@ class VortexStepper:
         ``reference=True`` runs the most conservative route: serial mesh,
         pure-jnp slabs, monolithic ordering — the ladder's last compute
         rung.  Returns host-side ``(tree, payload, ok, occ, health)``."""
-        if reference:
-            out = rk2_step(self.tree, dt, self.payload, p=self.p, mesh=None,
-                           use_kernels=False, plan=None, overlap=False,
-                           pipeline=False, guard=self.guard, faults=faults)
-        else:
-            out = rk2_step(
-                self.tree, dt, self.payload, p=self.p, mesh=self.mesh,
-                mesh_axis=self.mesh_axis, use_kernels=self.use_kernels,
-                plan=None if self.mesh is None
-                else (plan if plan is not None else self.plan),
-                overlap=self.overlap, pipeline=self.pipeline,
-                guard=self.guard, faults=faults)
+        with obs.span("stepper.dispatch"):
+            if reference:
+                out = rk2_step(self.tree, dt, self.payload, p=self.p,
+                               mesh=None, use_kernels=False, plan=None,
+                               overlap=False, pipeline=False,
+                               guard=self.guard, faults=faults)
+            else:
+                out = rk2_step(
+                    self.tree, dt, self.payload, p=self.p, mesh=self.mesh,
+                    mesh_axis=self.mesh_axis, use_kernels=self.use_kernels,
+                    plan=None if self.mesh is None
+                    else (plan if plan is not None else self.plan),
+                    overlap=self.overlap, pipeline=self.pipeline,
+                    guard=self.guard, faults=faults)
         tree, payload, ok, occ, health = out
-        jax.block_until_ready(tree.z)
-        return (tree, payload, bool(ok), int(occ),
-                None if health is None else np.asarray(health))
+        with obs.span("stepper.wait"):
+            jax.block_until_ready(tree.z)
+        # one span per device-to-host read: their count is the step's syncs
+        with obs.span("stepper.pull"):
+            ok = bool(ok)
+        with obs.span("stepper.pull"):
+            occ = int(occ)
+        if health is not None:
+            with obs.span("stepper.pull"):
+                health = np.asarray(health)
+        return tree, payload, ok, occ, health
 
     def _recover(self, first_health: np.ndarray):
         """Walk the recovery ladder for the step that just faulted.
@@ -910,14 +934,19 @@ class VortexStepper:
         Guarded steppers check the on-device health word and walk the
         recovery ladder on any fault; a rollback record carries
         ``recovered="rollback"`` and does NOT advance ``step_count``."""
+        with obs.span("stepper.step"):
+            return self._step()
+
+    def _step(self) -> StepRecord:
         t0 = time.perf_counter()
         recovered, releveled, fb_replanned = "", False, False
         tree, payload, ok, occ, health = self._run_rk2(
             self.dt, faults=self._active_faults(0))
         if self.guard:
             if not hw.ok(health):
-                (tree, payload, occ, health, recovered, releveled,
-                 fb_replanned) = self._recover(health)
+                with obs.span("stepper.recover"):
+                    (tree, payload, occ, health, recovered, releveled,
+                     fb_replanned) = self._recover(health)
                 if tree is None:        # rolled back: step did not advance
                     seconds = time.perf_counter() - t0
                     rec = StepRecord(step=self.step_count, seconds=seconds,
@@ -951,7 +980,8 @@ class VortexStepper:
         if self.step_count % self.replan_every == 0:
             # occ comes off the step's own outputs (already on host after
             # block_until_ready) — the check itself syncs nothing extra
-            action = self.maybe_replan(occ=int(occ))
+            with obs.span("stepper.replan"):
+                action = self.maybe_replan(occ=int(occ))
             replanned = replanned or action == "replan"
             releveled = releveled or action == "relevel"
         rec = StepRecord(step=self.step_count, seconds=seconds,
@@ -963,7 +993,8 @@ class VortexStepper:
         self.history.append(rec)
         if (self._ckpt is not None and self.checkpoint_every
                 and self.step_count % self.checkpoint_every == 0):
-            self.save_checkpoint()
+            with obs.span("stepper.checkpoint"):
+                self.save_checkpoint()
         return rec
 
     def stats(self) -> dict:

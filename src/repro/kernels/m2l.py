@@ -125,6 +125,7 @@ def m2l_pallas_slab(me_halo: jnp.ndarray, level: int, p: int, row0: int = 0,
         out_specs=[out_spec, out_spec],
         out_shape=out_shape,
         interpret=interpret,
+        name="m2l_kernel",
     )(sr, si, wr, wi)
 
     acc = (br[:PR, :PC, :p4] + 1j * bi[:PR, :PC, :p4]).astype(me_halo.dtype)

@@ -171,6 +171,7 @@ def p2p_pallas_slab(z_halo, q_halo, mask_halo, sigma=None,
         out_specs=[tgt_spec] * (2 * eq.nout),
         out_shape=out_shape,
         interpret=interpret,
+        name="p2p_kernel",
     )(*inputs)
 
     chans = [(outs[2 * c][:rows, :cols, :st] +

@@ -11,16 +11,20 @@ The topology is described inside a module-scoped fixture (never while a
 module is imported): only one process at a time may load the TPU library,
 and every test worker imports this file.
 """
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.core import equations as eqs
 from repro.core.fmm import fmm_evaluate
 from repro.core.quadtree import Tree
+from repro.core.stepper import rk2_step
 from repro.kernels import m2l as km
 from repro.kernels import ops
 from repro.kernels import p2p as kp
@@ -102,3 +106,68 @@ def test_fmm_velocity_kernel_program_compiles_for_v5e(one_chip, monkeypatch):
     text = fmm_evaluate.lower(tree, P, eq=eqs.VORTEX,
                               use_kernels=True).compile().as_text()
     assert text.count("tpu_custom_call") >= 2       # M2L levels + P2P
+
+
+def _kernel_body(match) -> str:
+    """A Mosaic kernel's serialized module, printed without its source
+    locations (which carry the name scopes, as HLO metadata does)."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(match.group(1)))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+def _small_tree(one_chip, level=5, slots=8):
+    n = 1 << level
+    return Tree(z=_struct((n, n, slots), jnp.complex64, one_chip),
+                q=_struct((n, n, slots), jnp.complex64, one_chip),
+                mask=_struct((n, n, slots), jnp.bool_, one_chip),
+                level=level, sigma=1e-3)
+
+
+def test_kernels_keep_their_names_for_v5e(one_chip, monkeypatch):
+    """The P2P and M2L custom calls take their ``pallas_call`` names, which
+    hold the words the trace's kernel readers select by; no other custom
+    call of the program holds either word."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    text = fmm_evaluate.lower(_small_tree(one_chip), P, eq=eqs.VORTEX,
+                              use_kernels=True).compile().as_text()
+    calls = re.findall(r"^\s+(?:ROOT )?%([\w.\-]+) = [^\n]*? custom-call\(",
+                       text, re.M)
+    named = {c.split(".")[0] for c in calls if "p2p" in c or "m2l" in c}
+    assert named == {"p2p_kernel", "m2l_kernel"}
+
+
+@pytest.mark.parametrize("program", ["fmm_evaluate", "rk2_step"])
+def test_stage_scopes_change_only_metadata_for_v5e(one_chip, monkeypatch,
+                                                   program):
+    """Compiled for the chip with and without ``obs.scope``, the programs
+    differ in their metadata alone."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    tree = _small_tree(one_chip, level=4)
+
+    def compiled():
+        jax.clear_caches()
+        if program == "fmm_evaluate":
+            lowered = fmm_evaluate.lower(tree, P, eq=eqs.VORTEX,
+                                         use_kernels=True)
+        else:
+            lowered = rk2_step.lower(tree, 0.005, p=P, use_kernels=True,
+                                     guard=True)
+        text = lowered.compile().as_text()
+        body = [ln for ln in text.splitlines()
+                if ln.startswith(("HloModule", "%", "ENTRY", " ", "}"))]
+        body = re.sub(r",? metadata=\{[^{}]*\}", "", "\n".join(body))
+        return re.sub(r'"body":"([^"]+)"', _kernel_body, body), text
+
+    shipped, shipped_text = compiled()
+    monkeypatch.setattr(obs, "scope", lambda name: contextlib.nullcontext())
+    bare, bare_text = compiled()
+    assert "fmm.p2p/" in shipped_text and "fmm.p2p/" not in bare_text
+    assert shipped == bare
