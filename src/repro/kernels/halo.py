@@ -6,15 +6,19 @@ halo'd array, at element offsets, so the halo never exists as a separate
 HBM buffer.  Mosaic constrains such windows:
 
   * every block axis is ``pl.Element`` or none is;
-  * the last two axes are tiled (8 sublanes, 128 lanes): a window's column
-    extent must be a multiple of 8 or the array's whole extent, and its
-    lane extent a multiple of 128 or whole.
+  * the last two axes are tiled (8 sublanes, 128 lanes): a window's extent
+    on the sublane axis must be a multiple of 8 or the array's whole
+    extent, and on the lane axis a multiple of 128 or whole.
 
-So ``BX`` is a multiple of 8, and a grid with several column blocks reads
-``round_up(BX + 2, 8)`` columns per window (``BX + 8``), of which the
-kernel uses ``BX + 2``; the halo'd array is zero-padded on the right so the
-last window stays in bounds.  A single column block reads the whole width.
-Rows are a leading, untiled axis: any ``BY`` works there.
+So a tiled box axis takes blocks that are whole tiles of it, and a grid
+with several blocks along it reads ``round_up(B + 2, align)`` boxes per
+window (``B + 8`` on sublanes), of which the kernel uses ``B + 2``; the
+halo'd array is zero-padded at the far end so the last window stays in
+bounds.  A single block reads the whole extent.  The M2L
+kernel keeps its box rows on a leading, untiled axis (``row_align=1``: any
+``BY``) and its box columns on sublanes.  The P2P kernel puts box rows on
+sublanes and box columns on lanes; it reads row windows, and takes its
+column halo from three column-shifted copies instead of a lane window.
 """
 from __future__ import annotations
 
@@ -38,6 +42,7 @@ class HaloTiling:
     BX: int
     rows_p: int        # rows padded to a multiple of BY
     cols_p: int        # cols padded to a multiple of BX
+    win_rows: int      # row extent of one halo window
     win_cols: int      # column extent of one halo window
 
     @property
@@ -45,15 +50,21 @@ class HaloTiling:
         return self.rows_p // self.BY, self.cols_p // self.BX
 
     @property
+    def halo_rows(self) -> int:
+        """Row extent the halo'd input array is padded to."""
+        return self.rows_p - self.BY + self.win_rows
+
+    @property
     def halo_cols(self) -> int:
         """Column extent the halo'd input array is padded to."""
         return self.cols_p - self.BX + self.win_cols
 
     def window_spec(self, lanes: int) -> pl.BlockSpec:
-        """(BY+2, win_cols, lanes) window over the halo'd array."""
+        """(win_rows, win_cols, lanes) window over the halo'd array."""
         BY, BX = self.BY, self.BX
         return pl.BlockSpec(
-            (pl.Element(BY + 2), pl.Element(self.win_cols), pl.Element(lanes)),
+            (pl.Element(self.win_rows), pl.Element(self.win_cols),
+             pl.Element(lanes)),
             lambda i, j: (i * BY, j * BX, 0))
 
     def block_spec(self, lanes: int) -> pl.BlockSpec:
@@ -62,11 +73,19 @@ class HaloTiling:
                             lambda i, j: (i, j, 0))
 
 
-def halo_tiling(rows: int, cols: int, block: tuple[int, int]) -> HaloTiling:
-    """Clip ``block`` to the grid and round ``BX`` up to whole sublanes."""
-    BY = max(1, min(block[0], rows))
-    BX = round_up(max(1, min(block[1], cols)), SUBLANES)
-    rows_p, cols_p = round_up(rows, BY), round_up(cols, BX)
-    win = BX + 2 if cols_p == BX else round_up(BX + 2, SUBLANES)
+def _halo_axis(n: int, b: int, align: int) -> tuple[int, int, int]:
+    """Block, padded extent and window extent of one halo'd box axis."""
+    B = round_up(max(1, min(b, n)), align)
+    n_p = round_up(n, B)
+    return B, n_p, B + 2 if n_p == B else round_up(B + 2, align)
+
+
+def halo_tiling(rows: int, cols: int, block: tuple[int, int],
+                row_align: int = 1, col_align: int = SUBLANES) -> HaloTiling:
+    """Clip ``block`` to the grid and round each axis up to whole tiles of
+    it: ``row_align``/``col_align`` are 1 (untiled), 8 (sublanes) or 128
+    (lanes)."""
+    BY, rows_p, win_rows = _halo_axis(rows, block[0], row_align)
+    BX, cols_p, win_cols = _halo_axis(cols, block[1], col_align)
     return HaloTiling(BY=BY, BX=BX, rows_p=rows_p, cols_p=cols_p,
-                      win_cols=win)
+                      win_rows=win_rows, win_cols=win_cols)

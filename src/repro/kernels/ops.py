@@ -10,13 +10,15 @@ grid form (serial driver — zero ghosts attached here) and the slab form
 §4/§5.
 
 Plan-aware block autotuning (DESIGN.md §5/§9; Holm et al., arXiv:1311.1006):
-``block=None`` resolves the ``(BY, BX)`` launch tiling from a small static
-table keyed by the launch-shape class the execution plan implies — the
-monolithic/interior tile, or one of the thin rim strips of the overlapped
-driver.  Block shape is a pure perf knob (bit-equivalent outputs, pinned by
-tests), so the table can be retuned per backend without touching numerics.
-Lane padding (``lane_pad=None`` -> pad on real TPU only) pads the kernels'
-lane axes (``s`` for P2P, ``4p`` for M2L) to multiples of 128 inside the
+for M2L, ``block=None`` resolves the ``(BY, BX)`` launch tiling from a
+small static table keyed by the launch-shape class the execution plan
+implies — the monolithic/interior tile, or one of the thin rim strips of
+the overlapped driver.  P2P takes its own block (``p2p.P2P_BLOCK``; the
+kernel lays the longer box axis of any launch on the lanes).  Block shape
+is a pure perf knob (equivalent outputs, pinned by tests), so either can be
+retuned per backend without touching numerics.  Lane padding
+(``lane_pad=None`` -> pad on real TPU only) pads the kernels' lane axes —
+the box columns for P2P, ``4p`` for M2L — to multiples of 128 inside the
 wrappers; padded lanes are structural zeros, so this too is numerics-free.
 
 Under the substep pipeline (DESIGN.md §12) the rim-strip launches of the
@@ -45,14 +47,13 @@ def _interpret() -> bool:
 # Plan-aware block autotuning
 # ---------------------------------------------------------------------------
 
-# Static (BY, BX) per launch-shape class.  Classes map onto what an
+# Static M2L (BY, BX) per launch-shape class.  Classes map onto what an
 # execution plan actually launches: full interior/monolithic tiles, wide
 # row-slab tiles, and the thin rim strips of the overlapped driver (a few
 # rows/cols spanning the whole tile edge).  Values are clipped to the
-# launch extents; the kernels round BX up to whole sublanes (8) and pad
-# non-dividing extents up to a block multiple (kernels/halo.py), and P2P
-# shrinks the block until its pair tensor fits (``p2p.pair_block``), so
-# any entry is legal for any shape.  Every BX here is a multiple of 8: a
+# launch extents; the kernel rounds BX up to whole sublanes (8) and pads
+# non-dividing extents up to a block multiple (kernels/halo.py), so any
+# entry is legal for any shape.  Every BX here is a multiple of 8: a
 # multi-block halo window then reads BX + 8 columns for the BX + 2 it uses.
 BLOCK_TABLE: dict[str, tuple[int, int]] = {
     "rim_row": (2, 32),     # thin row strips: keep the whole strip in one
@@ -98,13 +99,15 @@ def p2p_apply_slab(z_halo, q_halo, mask_halo, sigma,
                    lane_pad: bool | None = None, z_tgt=None, eq=None):
     """P2P over a slab with ±1 ghost rows/cols attached (sharded driver).
 
-    ``block=None`` autotunes ``(BY, BX)`` from the interior launch shape;
-    ``lane_pad=None`` pads the slot axes to lane multiples of 128 on real
-    TPU.  ``z_tgt`` selects passive-target evaluation and ``eq`` the
-    equation spec supplying the pair interaction (vortex default).
+    ``block=None`` takes ``p2p.P2P_BLOCK``; ``lane_pad=None`` pads the box
+    columns (the kernel's lane axis) to a multiple of 128 on real TPU.
+    ``z_tgt`` selects passive-target evaluation and ``eq`` the equation
+    spec supplying the pair interaction (vortex default).
     """
-    block, lane_pad = _resolve(block, z_halo.shape[0] - 2,
-                               z_halo.shape[1] - 2, lane_pad)
+    if lane_pad is None:
+        lane_pad = not _interpret()
+    if block is None:
+        block = _p2p.P2P_BLOCK
     return _p2p.p2p_pallas_slab(z_halo, q_halo, mask_halo, sigma=sigma,
                                 block=block, interpret=_interpret(),
                                 lane_pad=lane_pad, z_tgt=z_tgt, eq=eq)

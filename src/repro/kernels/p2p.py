@@ -3,32 +3,45 @@
 The P2P stage dominates FMM runtime (paper Eq 10, the ``d N B / P`` term),
 so it gets a hand-written kernel.  The old wrapper gathered each leaf box's
 3x3 neighborhood into a dense ``(boxes, 9s)`` source slab — 9x the particle
-data staged through HBM before the kernel even started.  This version stages
-nothing:
+data staged through HBM before the kernel even started.  This version
+stages a relayout and three column shifts:
 
+  * **boxes on the lanes.**  Each particle field is an f32 plane array
+    ``(s, rows, cols)``: the leaf slots are a leading axis, the box rows lie
+    on the 8 sublanes and the box columns on the 128 lanes (the longer of
+    the launch's two box axes, so a thin rim strip runs along the lanes).
+    A pair tensor is ``(tc, BY, BX)`` — ``tc`` target slots of every box in
+    the block against ONE source slot of the neighbour box — so each box
+    and neighbour offset costs exactly ``st * s`` pair evaluations, and no
+    slot axis is ever padded to 128.
   * the leaf grid is padded by ±1 box (zeros at the domain edge; under
     ``shard_map`` the ghost rows have already been exchanged by the caller)
-    and the Pallas grid tiles it into ``(BY, BX)`` blocks whose BlockSpecs
-    read **overlapping halo windows** directly from the padded grid
-    (``pl.Element`` offsets in the TPU-legal form of ``kernels/halo.py``);
-  * the kernel slices the 9 neighbor offsets out of its VMEM tile and
-    evaluates the pair interaction on the VPU, keeping the accumulators in
-    VMEM across the whole 9-offset reduction — one HBM write per output
-    tile, ``(BB, st, s)`` pair temporaries instead of the old
-    ``(BB, s, 9s)``;
+    and each source field is staged as three copies shifted by 0, 1 and 2
+    columns, so every neighbour offset's columns are one lane-aligned
+    block.  The Pallas grid tiles the grid into ``(BY, BX)`` blocks whose
+    BlockSpecs read **overlapping row windows** of ``BY + 8`` rows (or the
+    whole extent) directly from those planes (``pl.Element`` offsets in the
+    TPU-legal form of ``kernels/halo.py``);
+  * the kernel takes the 9 neighbour offsets as static ``[dx, j, dy:dy+BY]``
+    slices of its VMEM windows and loops over the source slots ``j``,
+    keeping the ``(tc, BY, BX)`` accumulators in registers across the whole
+    9-offset reduction — one HBM write per output block;
   * the pair interaction itself comes from the equation spec
     (``core/equations.py: p2p_terms`` — explicit real/imag arithmetic, the
     MXU/VPU have no complex type).  The kernel body is equation-independent
-    and emits ``eq.nout`` complex channels; passive source != target
-    evaluation (probe grids, tracers) runs through the SAME kernel with the
-    targets as a separate ``(BY, BX, st)`` block, while the default
-    source == target mode slices its targets out of the already-loaded
-    halo tile (no extra input streams — the pre-registry data path).
+    and emits ``eq.nout`` complex channels.  Targets always arrive as their
+    own ``(tc, BY, BX)`` blocks: the sources' own interior in the default
+    source == target mode, a separate point set in passive-target mode
+    (probe grids, tracers).
 
-Block sizing: the wrapper shrinks ``(BY, BX)`` until one offset's
-(BY*BX, st, s) f32 pair tensor fits ``PAIR_BYTES``, and the lane dimension
-(s) should be a multiple of 128 on real hardware (pad ``s`` accordingly;
-correctness does not depend on it).
+Block sizing: ``BY`` is a multiple of 8 and ``BX`` of 128 (``lane_pad``; 8
+in the interpreter), or the whole extent.  ``pair_block`` shrinks the
+block until one ``(1, BY, BX)`` pair tensor fits ``PAIR_BYTES``, and the
+target slots are split into grid chunks of ``tc`` so that one
+``(tc, BY, BX)`` tensor does.  The source slots are split into grid chunks
+too, so that the fifteen source windows fit ``WINDOW_BYTES`` of VMEM
+whatever the slot count; their partial sums accumulate in the resident
+output block.
 """
 from __future__ import annotations
 
@@ -42,67 +55,65 @@ from ..core import equations as _eqs
 from .halo import LANES, SUBLANES, halo_tiling, round_up
 
 P2P_HALO = 1    # ghost rows/cols of particle data needed by a slab
-PAIR_BYTES = 1 << 19   # bound on one offset's (BY*BX, st, s) f32 pair tensor
+P2P_BLOCK = (16, 128)    # default (BY, BX) box block of one launch
+PAIR_BYTES = 1 << 15     # bound on one (tc, BY, BX) f32 pair tensor
+WINDOW_BYTES = 1 << 22   # bound on one step's (3, sk, BY + 8, BX) windows
 
 
-def pair_block(block: tuple[int, int], st: int, s: int) -> tuple[int, int]:
-    """Shrink ``block`` so one offset's pair tensor fits ``PAIR_BYTES``:
-    ``BX`` down to one sublane tile first, then ``BY``."""
-    boxes = max(PAIR_BYTES // (4 * st * s), 1)
-    bx = min(block[1], max(boxes, SUBLANES))
-    by = max(min(block[0], boxes // round_up(bx, SUBLANES)), 1)
+def _chunk(n: int, cap: int) -> int:
+    """Size of the fewest equal chunks of at most ``cap`` that cover ``n``."""
+    return -(-n // -(-n // max(cap, 1)))
+
+
+def pair_block(block: tuple[int, int],
+               lanes: int = LANES) -> tuple[int, int]:
+    """Shrink ``block`` (rows first, down to one sublane tile, then columns,
+    down to ``lanes``) until one ``(1, BY, BX)`` f32 pair tensor fits
+    ``PAIR_BYTES``; both come out whole tiles."""
+    cap = PAIR_BYTES // 4
+    bx = max(lanes, min(round_up(block[1], lanes),
+                        cap // SUBLANES // lanes * lanes))
+    by = max(SUBLANES, min(round_up(block[0], SUBLANES),
+                           cap // bx // SUBLANES * SUBLANES))
     return by, bx
 
 
-def _p2p_kernel(*refs, eq, sigma: float | None, BY: int, BX: int,
-                st: int, s: int, tgt_from_src: bool):
-    if tgt_from_src:
-        zr_ref, zi_ref, qr_ref, qi_ref, m_ref, *out_refs = refs
-    else:
-        txr_ref, txi_ref, zr_ref, zi_ref, qr_ref, qi_ref, m_ref, \
-            *out_refs = refs
-    BB = BY * BX
-    if tgt_from_src:
-        # source == target mode: the targets ARE the halo window's interior
-        # — read them out of the source windows instead of paying two extra
-        # HBM->VMEM input streams (st == s here)
-        tx = zr_ref[1:1 + BY, 1:1 + BX, :].reshape(BB, st)
-        ty = zi_ref[1:1 + BY, 1:1 + BX, :].reshape(BB, st)
-    else:
-        tx = txr_ref[...].reshape(BB, st)        # (BY, BX, st) target block
-        ty = txi_ref[...].reshape(BB, st)
-    nout = len(out_refs) // 2
+def _p2p_kernel(txr_ref, txi_ref, *refs, eq, sigma: float | None, BY: int,
+                sk: int):
+    src_refs, out_refs = refs[:5], refs[5:]
+    tx = txr_ref[...]              # (tc, BY, BX) target block
+    ty = txi_ref[...]
 
-    def offset_row(r, accs):
-        # the three P2P_OFFSETS of source row dy = r - 1, in their order;
-        # a loop over rows keeps the kernel body (and its compile) 3x
-        # smaller than unrolling all nine offsets
-        accs = list(accs)
-        rows = pl.ds(r, BY)
-        for dx in range(3):
-            def src(ref):
-                return ref[rows, dx:dx + BX, :].reshape(BB, s)
-            sx, sy, sqr, sqi, sm = map(src, (zr_ref, zi_ref, qr_ref, qi_ref,
-                                             m_ref))
-            ddx = tx[:, :, None] - sx[:, None, :]        # (BB, st, s)
-            ddy = ty[:, :, None] - sy[:, None, :]
+    def pairs(dy, dx):
+        # source box (y + dy - 1, x + dx - 1), one of the 9 P2P_OFFSETS: the
+        # dx-shifted copy of each (3, sk, BY + 8, BX) window, rows dy..
+        def slot(j, accs):
+            sx, sy, sqr, sqi, sm = (ref[dx, j, dy:dy + BY, :][None]
+                                    for ref in src_refs)
+            ddx = tx - sx                                # (tc, BY, BX)
+            ddy = ty - sy
             r2 = ddx * ddx + ddy * ddy
-            valid = (sm[:, None, :] > 0) & (r2 > 0.0)
+            valid = (sm > 0) & (r2 > 0.0)
             moll = None
             if sigma is not None:
                 moll = 1.0 - jnp.exp(-r2 / (2.0 * sigma * sigma))
-            terms = eq.p2p_terms(ddx, ddy, r2, valid, sqr[:, None, :],
-                                 sqi[:, None, :], moll)
-            for c, (tre, tim) in enumerate(terms):
-                accs[2 * c] = accs[2 * c] + tre.sum(axis=-1)
-                accs[2 * c + 1] = accs[2 * c + 1] + tim.sum(axis=-1)
-        return tuple(accs)
+            terms = eq.p2p_terms(ddx, ddy, r2, valid, sqr, sqi, moll)
+            return tuple(a + t for a, t in
+                         zip(accs, (x for pair in terms for x in pair)))
+        return slot
 
-    accs = jax.lax.fori_loop(
-        0, 3, offset_row,
-        tuple(jnp.zeros((BB, st), jnp.float32) for _ in range(2 * nout)))
-    for i, ref in enumerate(out_refs):
-        ref[...] = accs[i].reshape(BY, BX, st)
+    accs = tuple(jnp.zeros(tx.shape, jnp.float32) for _ in out_refs)
+    for dy in range(3):
+        for dx in range(3):
+            accs = jax.lax.fori_loop(0, sk, pairs(dy, dx), accs)
+
+    @pl.when(pl.program_id(3) == 0)
+    def _():
+        for ref in out_refs:
+            ref[...] = jnp.zeros(ref.shape, jnp.float32)
+
+    for ref, acc in zip(out_refs, accs):
+        ref[...] += acc
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "block", "interpret",
@@ -121,62 +132,71 @@ def p2p_pallas_slab(z_halo, q_halo, mask_halo, sigma=None,
     no halo; masked-off target slots yield don't-care values the caller
     masks); None evaluates at the sources themselves.
 
-    ``lane_pad=True`` pads the slot axes up to lane multiples of 128
-    (real-TPU layout; DESIGN.md §5) — padded source slots carry ``mask=0``
-    so they are structurally excluded and the numerics are unchanged; the
-    output is sliced back to ``st``.  ``interpret=None`` runs the Pallas
-    interpreter only off-TPU (``kernels.ops._interpret``).
+    ``lane_pad=True`` pads the lane axis — the box columns — to a multiple
+    of 128 and takes ``BX`` in whole lane tiles (real-TPU layout; DESIGN.md
+    §5); ``False`` rounds ``BX`` to 8 columns only, which the interpreter
+    accepts.  Padded boxes carry ``mask=0`` so they are structurally
+    excluded and the numerics are unchanged; the output is sliced back.
+    ``interpret=None`` runs the Pallas interpreter only off-TPU
+    (``kernels.ops._interpret``).
     """
     if interpret is None:
         from .ops import _interpret
         interpret = _interpret()
     eq = _eqs.get_equation(eq)
-    rows, cols, s = (z_halo.shape[0] - 2, z_halo.shape[1] - 2,
-                     z_halo.shape[2])
-    tgt_from_src = z_tgt is None
-    st = s if tgt_from_src else z_tgt.shape[2]
-    sl = round_up(s, LANES) if lane_pad else s
-    stl = sl if tgt_from_src else (round_up(st, LANES) if lane_pad else st)
-    t = halo_tiling(rows, cols, pair_block(block, stl, sl))
+    s = z_halo.shape[2]
+    if z_tgt is None:
+        z_tgt = z_halo[1:-1, 1:-1]
+    st = z_tgt.shape[2]
+    # slots lead; the longer box axis goes on the lanes
+    swap = z_tgt.shape[0] > z_tgt.shape[1]
+    perm = (2, 1, 0) if swap else (2, 0, 1)
+    rows, cols = (z_tgt.shape[1], z_tgt.shape[0]) if swap else z_tgt.shape[:2]
+    lanes = LANES if lane_pad else SUBLANES
+    t = halo_tiling(rows, cols, pair_block(block, lanes),
+                    row_align=SUBLANES, col_align=lanes)
+    tc = _chunk(st, PAIR_BYTES // (4 * t.BY * t.BX))
+    sk = _chunk(s, WINDOW_BYTES // (5 * 3 * 4 * t.win_rows * t.BX))
+    st_p, s_p = round_up(st, tc), round_up(s, sk)
 
-    def prep(x, lanes, cols_to):
-        return jnp.pad(x.astype(jnp.float32),
-                       ((0, t.rows_p - rows), (0, cols_to - x.shape[1]),
-                        (0, lanes - x.shape[2])))
+    def planes(x, depth, rows_to, cols_to):
+        x = jnp.transpose(x.astype(jnp.float32), perm)
+        return jnp.pad(x, ((0, depth - x.shape[0]), (0, rows_to - x.shape[1]),
+                           (0, cols_to - x.shape[2])))
 
-    # halo'd sources (rows+2 -> rows_p+2 rows, halo_cols columns)
-    zr, zi, qr, qi, m = (prep(x, sl, t.halo_cols) for x in (
-        z_halo.real, z_halo.imag, q_halo.real, q_halo.imag, mask_halo))
+    tgt = [planes(x, st_p, t.rows_p, t.cols_p) for x in (z_tgt.real,
+                                                         z_tgt.imag)]
+    # each source field as three copies shifted by dx = 0, 1, 2 columns, so
+    # that every offset's columns are a lane-aligned block
+    src = [planes(x, s_p, t.halo_rows, t.cols_p + 2) for x in (
+        z_halo.real, z_halo.imag, q_halo.real, q_halo.imag, mask_halo)]
+    src = [jnp.stack([x[:, :, dx:dx + t.cols_p] for dx in range(3)])
+           for x in src]
 
-    halo_spec = t.window_spec(sl)
-    tgt_spec = t.block_spec(stl)
-    out_shape = [jax.ShapeDtypeStruct((t.rows_p, t.cols_p, stl),
+    BY, BX = t.BY, t.BX
+    grid = (*t.grid, st_p // tc, s_p // sk)
+    tgt_spec = pl.BlockSpec((tc, BY, BX), lambda i, j, c, k: (c, i, j))
+    win_spec = pl.BlockSpec(
+        (pl.Element(3), pl.Element(sk), pl.Element(t.win_rows),
+         pl.Element(BX)),
+        lambda i, j, c, k: (0, k * sk, i * BY, j * BX))
+    out_shape = [jax.ShapeDtypeStruct((st_p, t.rows_p, t.cols_p),
                                       jnp.float32)] * (2 * eq.nout)
 
-    if tgt_from_src:
-        inputs = (zr, zi, qr, qi, m)
-        in_specs = [halo_spec] * 5
-    else:
-        # bare targets (rows -> rows_p, cols -> cols_p)
-        txr = prep(z_tgt.real, stl, t.cols_p)
-        txi = prep(z_tgt.imag, stl, t.cols_p)
-        inputs = (txr, txi, zr, zi, qr, qi, m)
-        in_specs = [tgt_spec, tgt_spec] + [halo_spec] * 5
-
     outs = pl.pallas_call(
-        functools.partial(_p2p_kernel, eq=eq, sigma=sigma, BY=t.BY, BX=t.BX,
-                          st=stl, s=sl, tgt_from_src=tgt_from_src),
-        grid=t.grid,
-        in_specs=in_specs,
+        functools.partial(_p2p_kernel, eq=eq, sigma=sigma, BY=BY, sk=sk),
+        grid=grid,
+        in_specs=[tgt_spec] * 2 + [win_spec] * 5,
         out_specs=[tgt_spec] * (2 * eq.nout),
         out_shape=out_shape,
         interpret=interpret,
         name="p2p_kernel",
-    )(*inputs)
+    )(*tgt, *src)
 
-    chans = [(outs[2 * c][:rows, :cols, :st] +
-              1j * outs[2 * c + 1][:rows, :cols, :st]).astype(z_halo.dtype)
-             for c in range(eq.nout)]
+    back = (2, 1, 0) if swap else (1, 2, 0)
+    chans = [jnp.transpose(outs[2 * c][:st, :rows, :cols] +
+                           1j * outs[2 * c + 1][:st, :rows, :cols], back)
+             .astype(z_halo.dtype) for c in range(eq.nout)]
     return chans[0] if eq.nout == 1 else jnp.stack(chans, axis=-1)
 
 

@@ -76,6 +76,73 @@ def test_p2p_slab_matches_grid():
                                rtol=2e-5, atol=1e-5)
 
 
+def _slab_inputs(rng, rows, cols, s, st, q_real=False):
+    sh = (rows + 2, cols + 2, s)
+    z = jnp.asarray(rng.uniform(size=sh) + 1j * rng.uniform(size=sh),
+                    jnp.complex64)
+    q = rng.normal(size=sh) + (0 if q_real else 1j * rng.normal(size=sh))
+    mask = jnp.asarray(rng.uniform(size=sh) > 0.3)
+    z_tgt = None if st is None else jnp.asarray(
+        rng.uniform(size=(rows, cols, st))
+        + 1j * rng.uniform(size=(rows, cols, st)), jnp.complex64)
+    return z, jnp.asarray(q, jnp.complex64), mask, z_tgt
+
+
+@pytest.mark.parametrize("rows,cols,s,st,eq,lane_pad", [
+    (6, 10, 1, None, None, False),       # one slot
+    (9, 12, 4, None, None, True),        # 12 columns pad to one 128-lane block
+    (4, 6, 50, None, None, True),        # 50 slots: several target chunks
+    (3, 256, 4, None, None, True),       # two 128-lane column blocks
+    (5, 200, 5, None, None, True),       # 200 columns pad to 256
+    (20, 6, 3, None, None, True),        # rows > cols: rows go on the lanes
+    (8, 8, 5, 3, None, False),           # passive targets, st < s
+    (7, 9, 4, 9, None, True),            # passive targets, st > s
+    (6, 9, 5, None, "laplace", False),   # two output channels
+])
+@pytest.mark.parametrize("sigma", [None, 0.05])
+def test_p2p_kernel_matches_slab_reference(rows, cols, s, st, eq, lane_pad,
+                                           sigma):
+    """Boxes on the lanes, slots leading: the kernel equals the jnp slab
+    reference for any slot count, column extent and target mode."""
+    from repro.core import equations as eqs
+    from repro.core.fmm import p2p_slab_reference
+    rng = np.random.default_rng(rows * 1000 + cols * 10 + s)
+    z, q, mask, z_tgt = _slab_inputs(rng, rows, cols, s, st,
+                                     eqs.get_equation(eq).q_is_real)
+    out = p2p_pallas_slab(z, q, mask, sigma=sigma, lane_pad=lane_pad,
+                          z_tgt=z_tgt, eq=eq)
+    want = p2p_slab_reference(z, q, mask, sigma, z_tgt=z_tgt, eq=eq)
+    assert out.shape == want.shape
+    if z_tgt is None:                      # masked-off targets: don't care
+        m = np.asarray(mask[1:-1, 1:-1])
+        m = m[..., None] if out.ndim == 4 else m
+        out, want = np.where(m, out, 0), np.where(m, want, 0)
+    assert _rel(out, want) < 1e-5
+
+
+def test_p2p_source_slot_chunks_accumulate(monkeypatch):
+    """Slots beyond one VMEM window split into source chunks whose partial
+    sums accumulate in the resident output block."""
+    from repro.core.fmm import p2p_slab_reference
+    from repro.kernels import p2p as kp
+    rng = np.random.default_rng(31)
+    z, q, mask, _ = _slab_inputs(rng, 5, 11, 13, None)
+    want = np.asarray(p2p_pallas_slab(z, q, mask, sigma=0.05))
+    # five fields, three column shifts, one 10 x 8 f32 window a slot: four
+    # slots a chunk
+    monkeypatch.setattr(kp, "WINDOW_BYTES", 5 * 3 * 4 * 10 * 8 * 4)
+    jax.clear_caches()
+    try:
+        out = np.asarray(p2p_pallas_slab(z, q, mask, sigma=0.05))
+    finally:
+        jax.clear_caches()
+    ref_out = np.asarray(p2p_slab_reference(z, q, mask, 0.05))
+    m = np.asarray(mask[1:-1, 1:-1])
+    np.testing.assert_allclose(np.where(m, out, 0), np.where(m, want, 0),
+                               rtol=1e-5, atol=1e-5)
+    assert _rel(np.where(m, out, 0), np.where(m, ref_out, 0)) < 1e-5
+
+
 # ---------------------------------------------------------------------------
 # M2L kernel (parity-folded, halo-resident)
 # ---------------------------------------------------------------------------
@@ -200,10 +267,16 @@ def test_halo_tiling_is_tpu_legal(rows, cols, block, want):
 
 
 def test_p2p_pair_block_bounds_pair_tensor():
+    """Blocks come out whole (8, 128) tiles, or (8, 8) in the interpreter,
+    and one (1, BY, BX) f32 pair tensor fits PAIR_BYTES."""
     from repro.kernels.p2p import PAIR_BYTES, pair_block
-    assert pair_block((8, 16), 5, 5) == (8, 16)       # small slots: as asked
-    by, bx = pair_block((8, 16), 128, 128)            # lane-padded slots
-    assert bx % 8 == 0 and by * bx * 128 * 128 * 4 <= PAIR_BYTES
+    assert pair_block((8, 16)) == (8, 128)            # columns: whole lanes
+    assert pair_block((4, 4), lanes=8) == (8, 8)      # interpreter
+    assert pair_block((16, 128)) == (16, 128)         # as asked
+    for block in ((64, 1024), (1024, 64), (1, 1)):
+        by, bx = pair_block(block)
+        assert by % 8 == 0 and bx % 128 == 0
+        assert by * bx * 4 <= PAIR_BYTES
 
 
 def test_m2l_lane_pad_and_autotune_block_equivalence():
@@ -232,9 +305,26 @@ def test_m2l_lane_pad_and_autotune_block_equivalence():
         np.testing.assert_allclose(odd, base, rtol=1e-6, atol=1e-6)
 
 
+def _pallas_out_shapes(fn, *args):
+    """Output shapes of every ``pallas_call`` that ``fn`` traces."""
+    shapes = []
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                shapes.extend(v.aval.shape for v in e.outvars)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return shapes
+
+
 def test_p2p_lane_pad_and_autotune_block_equivalence():
+    """``lane_pad`` pads the lane axis, the box columns, to 128 and leaves
+    the slots unpadded on their leading axis; it, block=None autotuning and
+    non-dividing blocks all reproduce the default launch."""
     rng = np.random.default_rng(22)
-    ny, nx, s = 6, 12, 5                              # s = 5 pads to 128
+    ny, nx, s = 6, 12, 5                       # 12 columns pad to 128 lanes
     z = jnp.asarray(rng.uniform(size=(ny, nx, s)) + 1j * rng.uniform(size=(ny, nx, s)),
                     jnp.complex64)
     q = jnp.asarray(rng.normal(size=(ny, nx, s)) + 0j, jnp.complex64)
@@ -242,6 +332,11 @@ def test_p2p_lane_pad_and_autotune_block_equivalence():
     base = np.asarray(p2p_pallas(z, q, mask, sigma=0.05, block=(4, 4)))
     padded = np.asarray(p2p_pallas(z, q, mask, sigma=0.05, block=(4, 4),
                                    lane_pad=True))
+    for lane_pad, cols in ((False, 16), (True, 128)):
+        launch = _pallas_out_shapes(
+            lambda *a: p2p_pallas(*a, sigma=0.05, block=(4, 4),
+                                  lane_pad=lane_pad), z, q, mask)
+        assert set(launch) == {(s, 8, cols)}     # (slots, rows_p, cols_p)
     m = np.asarray(mask)
     np.testing.assert_allclose(np.where(m, padded, 0), np.where(m, base, 0),
                                rtol=1e-6, atol=1e-6)

@@ -32,6 +32,7 @@ from repro.kernels import p2p as kp
 P = 17                # the paper's expansion order
 EVAL_LEVEL = 10       # the paper's tree, 4 slots per leaf
 STEP_LEVEL, STEP_SLOTS = 8, 50   # VortexStepper's choice for 765,625 particles
+TILE = 1 << (EVAL_LEVEL - 1)     # leaf tile of the level-10 grid, 2x2 plan
 
 
 @pytest.fixture(scope="module")
@@ -73,23 +74,36 @@ def test_m2l_kernel_compiles_for_v5e(one_chip, cls, rows, cols):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("cls,rows,cols,slots", [
-    ("tile", 1 << EVAL_LEVEL, 1 << EVAL_LEVEL, 4),       # evaluate, level 10
-    ("rim_col", 1 << (STEP_LEVEL - 2), 1, STEP_SLOTS),   # stepper rim strip
+@pytest.mark.parametrize("rows,cols,slots", [
+    (1 << EVAL_LEVEL, 1 << EVAL_LEVEL, 4),               # evaluate, level 10
+    (1 << STEP_LEVEL, 1 << STEP_LEVEL, STEP_SLOTS),      # stepper, level 8
+    (TILE - 2, TILE - 2, 4),                             # 2x2 plan interior
+    (1, TILE, 4),                                        # its row rim strip
+    (TILE, 1, 4),                                        # its column rim strip
 ])
-def test_p2p_kernel_compiles_for_v5e(one_chip, cls, rows, cols, slots):
-    """Leaf launch (rows, cols) with +-1 ghosts, slots padded to 128."""
-    block = ops.autotune_block(rows, cols)
-    assert ops._shape_class(rows, cols) == cls
+def test_p2p_kernel_compiles_for_v5e(one_chip, rows, cols, slots):
+    """Leaf launch (rows, cols) with +-1 ghosts at the default block, the
+    box columns padded to 128 lanes.  Every operand and result of the
+    kernel keeps the slots on its leading axis, rounded up to a chunk and
+    never padded to 128, and the longer box axis on the lanes."""
     shape = (rows + 2, cols + 2, slots)
     args = (_struct(shape, jnp.complex64, one_chip),
             _struct(shape, jnp.complex64, one_chip),
             _struct(shape, jnp.bool_, one_chip))
     text = _compiled_text(
-        lambda z, q, m: kp.p2p_pallas_slab(z, q, m, sigma=1e-3, block=block,
+        lambda z, q, m: kp.p2p_pallas_slab(z, q, m, sigma=1e-3,
+                                           block=kp.P2P_BLOCK,
                                            interpret=False, lane_pad=True),
         *args)
-    assert "tpu_custom_call" in text
+    calls = [ln for ln in text.splitlines()
+             if "p2p_kernel" in ln and " custom-call(" in ln]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    dims = [tuple(map(int, d.split(",")))
+            for d in re.findall(r"f32\[([\d,]+)\]", calls[0])]
+    assert len(dims) == 2 + 5 + 2          # targets, sources, outputs
+    for d in dims:                         # (.., slots, box rows, box cols)
+        assert slots <= d[-3] < 2 * slots
+        assert d[-1] == -(-max(rows, cols) // 128) * 128
 
 
 def test_fmm_velocity_kernel_program_compiles_for_v5e(one_chip, monkeypatch):
