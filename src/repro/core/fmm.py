@@ -21,6 +21,7 @@ separate batch of passive target points (the ``tracer`` mode).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -120,6 +121,40 @@ def p2p_slab_fn(use_kernels: bool = False, eq=None):
         return p2p_slab_reference(z_halo, q_halo, mask_halo, sigma,
                                   z_tgt=z_tgt, eq=eq)
     return fn
+
+
+def p2p_chunk_reference(tgt, src, run_len, sigma, eq=None):
+    """Pure-jnp P2P of chunk runs given as rows, as the kernel takes them:
+    ``tgt`` (U, 2s) target positions (x, then y), ``src`` (U, R*5s) for
+    each source chunk its positions, charges and occupancy, ``run_len``
+    (U,) the live source chunks of each run.  Returns (2 * eq.nout, s, U):
+    each channel's real and imaginary parts, from the equation's
+    ``p2p_terms`` as in the kernel."""
+    eq = eqs.get_equation(eq)
+    U, two_s = tgt.shape
+    s = two_s // 2
+    R = src.shape[1] // (5 * s)
+    t = tgt.T.reshape(2, s, 1, U)
+    sx, sy, sqr, sqi, sm = jnp.transpose(src.reshape(U, R, 5, s),
+                                         (2, 1, 3, 0)).reshape(5, 1, R * s, U)
+    on = jnp.repeat(jnp.arange(R)[:, None] < run_len, s, axis=0)  # (R*s, U)
+    ddx, ddy = t[0] - sx, t[1] - sy
+    r2 = ddx * ddx + ddy * ddy
+    moll = None if sigma is None else 1.0 - jnp.exp(-r2 / (2.0 * sigma
+                                                            * sigma))
+    terms = eq.p2p_terms(ddx, ddy, r2, (sm > 0) & on & (r2 > 0.0), sqr, sqi,
+                         moll)
+    return jnp.stack([x.sum(axis=1) for pair in terms for x in pair])
+
+
+def p2p_chunk_fn(use_kernels: bool = False, eq=None):
+    """Returns ``fn(tgt, src, run_len, sigma) -> planes`` over chunk runs:
+    the Pallas chunk-pair kernel or its jnp reference."""
+    eq = eqs.get_equation(eq)
+    if use_kernels:
+        from ..kernels import ops as kops
+        return functools.partial(kops.p2p_apply_chunks, eq=eq)
+    return functools.partial(p2p_chunk_reference, eq=eq)
 
 
 # ---------------------------------------------------------------------------
@@ -244,7 +279,8 @@ def p2p_tile_overlapped(p2p_slab, z, q, mask, z_buf, q_buf, m_buf,
 
 
 def upward_sweep(tree: Tree, p: int, eq=None) -> list[jnp.ndarray]:
-    """Build normalized MEs for every level; returns me[l] for l=0..L."""
+    """Build normalized MEs for every level; returns me[l] for l=0..L.
+    Spill chunks add their moments to their boxes' leaf expansions."""
     eq = eqs.get_equation(eq)
     L = tree.level
     me = [None] * (L + 1)
@@ -252,6 +288,10 @@ def upward_sweep(tree: Tree, p: int, eq=None) -> list[jnp.ndarray]:
         centers = jnp.asarray(box_centers(L), dtype=tree.z.dtype)
         me[L] = ex.p2m(tree.z, tree.q, tree.mask, centers, box_size(L), p,
                        coeff=eq.p2m_coeff(p))
+        sp = tree.spill
+        if sp is not None:
+            with obs.scope("fmm.spill"):
+                me[L] = me[L] + _spill_moments(tree, centers, p, eq)
     mop = eq.m2m_operator(p)
     with obs.scope("fmm.m2m"):
         for l in range(L, 0, -1):
@@ -292,6 +332,84 @@ def near_field(tree: Tree, p2p_fn=None, z_tgt=None) -> jnp.ndarray:
                     jnp.pad(tree.mask, pad), tree.sigma, z_tgt)
 
 
+def _spill_moments(tree: Tree, centers, p: int, eq) -> jnp.ndarray:
+    """The spill chunks' multipole moments about their boxes' centres,
+    summed per box and laid on the (n, n, p) leaf grid.
+
+    Both sums are gathers over static index lists (``Spill.box_chunks``,
+    then ``window_box``) and the window is padded into place: a scatter
+    into the grid runs update by update on a TPU (7.9 ms for 59,151 of
+    them), and one into an (n*n, p) array fixes the layout of every
+    level-L grid the sweeps derive from it, padded 64-fold."""
+    sp = tree.spill
+    n, L = tree.nside, tree.level
+    y0, x0, wy, wx = sp.window
+    cw = centers[y0:y0 + wy, x0:x0 + wx].reshape(wy * wx)
+    mk = ex.p2m(sp.z, sp.q, sp.mask, cw[sp.chunk_wbox], box_size(L), p,
+                coeff=eq.p2m_coeff(p))                        # (K, p)
+    zero = jnp.zeros((1, p), mk.dtype)
+    per_box = jnp.concatenate([mk, zero])[sp.box_chunks].sum(axis=1)
+    win = jnp.concatenate([per_box, zero])[sp.window_box]     # (W, p)
+    return jnp.pad(win.reshape(wy, wx, p),
+                   ((y0, n - y0 - wy), (x0, n - x0 - wx), (0, 0)))
+
+
+def spill_near_field(tree: Tree, chunk_fn) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The near field of every pair of neighbouring chunks with a spill
+    chunk in it (``Spill``'s runs), by ``chunk_fn`` (``p2p_chunk_fn``).
+
+    The window's dense boxes, the spill chunks and ``run`` empty chunks
+    form one table, a row of ``5 s`` floats a chunk (x, y, Re q, Im q,
+    mask), and ``run`` shifted copies of it side by side hold in row ``c``
+    the chunks ``c .. c + run - 1``: each run is then one whole row, and
+    its sources come in one row gather.  (A gather of partial rows, or of
+    ``vmap``-ed dynamic slices, runs index by index on a TPU: 0.36 s for
+    703,001 runs.)  The chunk kernel takes these rows as they come and
+    puts the runs on the lanes itself, in VMEM: the output is ``(s, U)``
+    planes, since a ``(U, s)`` array would pad its ``s`` slots to 128
+    lanes on a TPU.  A target's runs are adjacent and its group gives
+    their number, so their sum is a reshape, with no scatter.  Returns
+    ``(dense, spill)``: the part to add to the dense grid's near field,
+    (n, n, s[, C]), and the spill chunks' whole near field, (K, s[, C]).
+    """
+    sp = tree.spill
+    n, s, R = tree.nside, tree.slots, sp.run
+    y0, x0, wy, wx = sp.window
+    with obs.scope("fmm.p2p"), obs.scope("fmm.spill"):
+        def chunks(z, q, m):        # (chunks, 5 s) f32 rows
+            return jnp.concatenate([z.real, z.imag, q.real, q.imag,
+                                    m.astype(z.real.dtype)], axis=-1)
+        win = lambda a: a[y0:y0 + wy, x0:x0 + wx].reshape(wy * wx, s)
+        table = jnp.concatenate([
+            chunks(win(tree.z), win(tree.q), win(tree.mask)),
+            chunks(sp.z, sp.q, sp.mask),
+            jnp.zeros((R, 5 * s), jnp.float32)])
+        rows = table.shape[0] - R
+        shifted = jnp.concatenate([table[r:r + rows] for r in range(R)],
+                                  axis=1)
+        src = shifted[sp.run_start]                           # (U, R*5s)
+        tgt = table[:rows, :2 * s][sp.run_tgt]                # (U, 2s)
+        w = chunk_fn(tgt, src, sp.run_len, tree.sigma)        # (2C, s, U)
+        parts, at = [], 0
+        for runs, targets in sp.groups:
+            parts.append(w[..., at:at + runs * targets]
+                         .reshape(w.shape[:2] + (targets, runs)).sum(-1))
+            at += runs * targets
+        acc = jnp.concatenate(parts + [jnp.zeros(w.shape[:2] + (1,),
+                                                 w.dtype)], axis=-1)
+
+        def cplx(planes):           # (2C, s, ...) -> (..., s[, C])
+            c = planes[0::2] + 1j * planes[1::2]
+            c = jnp.moveaxis(c, (0, 1), (-1, -2))
+            return (c[..., 0] if c.shape[-1] == 1 else c).astype(tree.z.dtype)
+        spill = cplx(jnp.take(acc, sp.spill_pos, axis=-1))
+        dense = cplx(jnp.take(acc, sp.window_pos, axis=-1)
+                     .reshape(acc.shape[:2] + (wy, wx)))
+        pad = ((y0, n - y0 - wy), (x0, n - x0 - wx)) + \
+            ((0, 0),) * (dense.ndim - 2)
+        return jnp.pad(dense, pad), spill
+
+
 def _mask_channels(mask, out):
     """Zero masked slots, broadcasting over trailing output channels."""
     m = mask if out.ndim == mask.ndim else mask[..., None]
@@ -313,6 +431,14 @@ def fmm_evaluate(tree: Tree, p: int, eq=None, use_kernels: bool = False,
     Pallas kernels (interpret mode off-TPU); both routes share the
     parity-folded slab implementations above.
 
+    A tree with spill chunks (``quadtree.Spill``) returns the pair
+    ``(dense, spill)``: the dense grid's output as above and the chunks'
+    (K, s[, C]); ``quadtree.gather_particle_values`` reads either form.
+    The chunks' work -- their P2M and L2P, and the near field of every
+    chunk pair with a spill chunk in it -- sits under the scope
+    ``fmm.spill``; a dense tree traces none of it.  Passive targets take
+    dense trees.
+
     ``with_health=True`` additionally returns a ``health.N_FIELDS`` int32
     health word computed inside the same program (non-finite sentinels on
     the leaf expansion coefficients and the masked output — the serial
@@ -324,49 +450,68 @@ def fmm_evaluate(tree: Tree, p: int, eq=None, use_kernels: bool = False,
         raise ValueError(f"equation {eq.name!r} requires a targets tree")
     if targets is not None and targets.level != tree.level:
         raise ValueError("targets tree level != source tree level")
+    if targets is not None and (tree.spill is not None
+                                or targets.spill is not None):
+        raise ValueError("passive targets take dense trees: spill chunks "
+                         "are evaluated at the sources only")
+    sp = tree.spill
     if eq.q_is_real:
         # real-charge equations read only Re q, in BOTH drivers: the
         # sharded halo exchange drops the Im q plane, so projecting here
         # keeps serial == sharded even on a tree whose charges were built
         # with a mismatched (complex) charge_scale
-        tree = Tree(z=tree.z, q=(tree.q.real + 0j).astype(tree.q.dtype),
-                    mask=tree.mask, level=tree.level, sigma=tree.sigma)
+        real = lambda q: (q.real + 0j).astype(q.dtype)
+        if sp is not None:
+            sp = dataclasses.replace(sp, q=real(sp.q))
+        tree = dataclasses.replace(tree, q=real(tree.q), spill=sp)
     L = tree.level
     p2p = p2p_slab_fn(use_kernels, eq)
     zt = None if targets is None else targets.z
     out_mask = tree.mask if targets is None else targets.mask
-    if L < 2:
-        # Tiny trees are all near field.
-        near = near_field(tree, p2p_fn=p2p, z_tgt=zt)
-        with obs.scope("fmm.assemble"):
-            out = _mask_channels(out_mask, near)
-        if not with_health:
-            return out
-        with obs.scope("fmm.health"):
-            health = hw.with_flag(hw.empty(), hw.F_VEL,
-                                  hw.nonfinite(out, out_mask))
-        return out, health
-    m2l_fn = m2l_grid_fn(p, use_kernels, eq)
-
-    me = upward_sweep(tree, p, eq)
-    le = downward_sweep(me, p, m2l_fn=m2l_fn)
-    z_eval = tree.z if targets is None else targets.z
-    with obs.scope("fmm.l2p"):
-        centers = jnp.asarray(box_centers(L), dtype=tree.z.dtype)
-        far = ex.l2p_eval(le[L], z_eval, out_mask, centers, box_size(L), p,
-                          eq.l2p_modes)
+    me = le = far = spill_far = None
+    if L >= 2:
+        # (tiny trees are all near field)
+        m2l_fn = m2l_grid_fn(p, use_kernels, eq)
+        me = upward_sweep(tree, p, eq)
+        le = downward_sweep(me, p, m2l_fn=m2l_fn)
+        z_eval = tree.z if targets is None else targets.z
+        with obs.scope("fmm.l2p"):
+            centers = jnp.asarray(box_centers(L), dtype=tree.z.dtype)
+            far = ex.l2p_eval(le[L], z_eval, out_mask, centers, box_size(L),
+                              p, eq.l2p_modes)
+            if sp is not None:
+                with obs.scope("fmm.spill"):
+                    y0, x0, wy, wx = sp.window
+                    at = lambda g: g[y0:y0 + wy, x0:x0 + wx].reshape(
+                        wy * wx, -1)[sp.chunk_wbox]
+                    spill_far = ex.l2p_eval(
+                        at(le[L]), sp.z, sp.mask, at(centers)[:, 0],
+                        box_size(L), p, eq.l2p_modes)
     near = near_field(tree, p2p_fn=p2p, z_tgt=zt)
+    if sp is not None:
+        dense_add, spill_near = spill_near_field(
+            tree, p2p_chunk_fn(use_kernels, eq))
+        near = near + dense_add
     with obs.scope("fmm.assemble"):
-        out = _mask_channels(out_mask, far + near)
+        out = _mask_channels(out_mask, near if far is None else far + near)
+        if sp is not None:
+            with obs.scope("fmm.spill"):
+                spill_out = _mask_channels(
+                    sp.mask, spill_near if far is None
+                    else spill_far + spill_near)
     if not with_health:
-        return out
+        return out if sp is None else (out, spill_out)
     with obs.scope("fmm.health"):
         health = hw.empty()
-        health = hw.with_flag(health, hw.F_COEFF,
-                              jnp.maximum(hw.nonfinite(me[L]),
-                                          hw.nonfinite(le[L])))
-        health = hw.with_flag(health, hw.F_VEL, hw.nonfinite(out, out_mask))
-    return out, health
+        if me is not None:
+            health = hw.with_flag(health, hw.F_COEFF,
+                                  jnp.maximum(hw.nonfinite(me[L]),
+                                              hw.nonfinite(le[L])))
+        bad = hw.nonfinite(out, out_mask)
+        if sp is not None:
+            bad = jnp.maximum(bad, hw.nonfinite(spill_out, sp.mask))
+        health = hw.with_flag(health, hw.F_VEL, bad)
+    return (out if sp is None else (out, spill_out)), health
 
 
 def fmm_velocity(tree: Tree, p: int, use_kernels: bool = False,
@@ -384,8 +529,7 @@ def fmm_velocity_singular(tree: Tree, p: int) -> jnp.ndarray:
     direct sum measures the p-convergence of the expansions alone
     (no Type-I kernel-substitution error; cf. paper §7.1 and ref [8]).
     """
-    sing = Tree(z=tree.z, q=tree.q, mask=tree.mask, level=tree.level, sigma=None)
-    return fmm_velocity(sing, p)
+    return fmm_velocity(dataclasses.replace(tree, sigma=None), p)
 
 
 def flops_estimate(tree_level: int, slots: int, p: int, eq=None,

@@ -83,7 +83,7 @@ from . import fmm
 from . import health as hw
 from .. import obs
 from .plan import BlockPlan, SlabPlan, uniform_plan
-from .quadtree import Tree, box_centers, box_size
+from .quadtree import Tree, box_centers, box_size, require_dense
 
 def _tile_halo(x: jnp.ndarray, width: int, rows_valid, cols_valid,
                axis_name: str, grid: tuple[int, int]) -> jnp.ndarray:
@@ -466,6 +466,7 @@ def parallel_fmm_evaluate(tree: Tree, p: int, mesh: Optional[Mesh] = None,
     device-tile layout): the body consumes it instead of issuing its own
     exchange round.
     """
+    require_dense(tree, "parallel_fmm_evaluate")
     eq = _eqs.get_equation(eq)
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
@@ -565,6 +566,7 @@ def parallel_fmm_p2p_prefetch(tree: Tree, mesh: Optional[Mesh] = None,
     fallbacks mirror :func:`parallel_fmm_evaluate` so the pair always
     agrees on the layout.
     """
+    require_dense(tree, "parallel_fmm_p2p_prefetch")
     eq = _eqs.get_equation(eq)
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()[:1]), ("data",))

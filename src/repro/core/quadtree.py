@@ -215,12 +215,76 @@ def box_centers(level: int) -> np.ndarray:
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
+class Spill:
+    """Overflow ("spill") chunks of a tree's crowded boxes.
+
+    A box holding more particles than the dense capacity ``s`` keeps its
+    first ``s`` in the dense grid; the rest fill chunks of ``s`` slots each,
+    ``K`` in all, ordered by box.  ``box`` is each chunk's flat row-major
+    leaf box.  Everything the chunks touch lies in ``window``, ``(y0, x0,
+    wy, wx)``: the boxes with chunks and their neighbours.
+
+    **Near field.**  One chunk index space: ``i < W = wy * wx`` is the
+    dense part of window box ``i`` (row-major), ``W + k`` spill chunk
+    ``k``.  A box's 3x3 neighbourhood is three rows of three boxes, and
+    the chunks of one such row are consecutive in both parts, so each
+    target chunk's sources are a few *runs* of at most ``run`` consecutive
+    chunks: ``run_start``/``run_len``/``run_tgt`` (U,).  The runs cover
+    every pair of neighbouring chunks of which at least one is a spill
+    chunk; the dense grid's own kernel does the rest.  Targets are ordered
+    by their number of runs, and ``groups`` lists ``(runs, targets)`` for
+    each count, so that summing a target's runs is a reshape.
+    ``spill_pos`` (K,) and ``window_pos`` (W,) give each spill chunk's and
+    each window box's place in that target order (``T``, past the end,
+    where a window box is no target).
+
+    **Far field.**  ``box_chunks`` (B, Cmax) lists the chunks of each of
+    the ``B`` boxes with chunks (``K`` pads), ``window_box`` (W,) each
+    window box's place among them (``B``: none), and ``chunk_wbox`` (K,)
+    each chunk's window box.
+
+    ``particles`` and ``pairs`` count the spilled particles and the chunk
+    pairs the runs cover (empty dense boxes included).
+    """
+
+    z: jax.Array           # complex64 (K, s) particle positions
+    q: jax.Array           # complex64 (K, s) pseudo-charges
+    mask: jax.Array        # bool      (K, s) slot occupancy
+    box: jax.Array         # int32 (K,)  flat leaf box of each chunk
+    chunk_wbox: jax.Array  # int32 (K,)  window box of each chunk
+    run_tgt: jax.Array     # int32 (U,)  target chunk of each run
+    run_start: jax.Array   # int32 (U,)  first source chunk of each run
+    run_len: jax.Array     # int32 (U,)  source chunks in each run
+    spill_pos: jax.Array   # int32 (K,)  target place of each spill chunk
+    window_pos: jax.Array  # int32 (W,)  target place of each window box
+    box_chunks: jax.Array  # int32 (B, Cmax) chunks of each box with some
+    window_box: jax.Array  # int32 (W,)  place of each window box among B
+    window: tuple = dataclasses.field(metadata=dict(static=True))
+    run: int = dataclasses.field(metadata=dict(static=True))
+    groups: tuple = dataclasses.field(metadata=dict(static=True))
+    particles: int = dataclasses.field(metadata=dict(static=True))
+    pairs: int = dataclasses.field(metadata=dict(static=True))
+
+    @property
+    def chunks(self) -> int:
+        return self.z.shape[0]
+
+
+# Chunks per run of the spill near field: longer runs mean fewer, larger
+# gathers, and more padded pairs.
+SPILL_RUN = 8
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
 class Tree:
-    """Dense uniform quadtree of particles.
+    """Dense uniform quadtree of particles, plus spill chunks.
 
     ``z``/``q``/``mask`` have shape ``(n, n, s)`` with ``n = 2**level`` leaf
     boxes per side and ``s`` padded slots per box.  ``q`` already includes
     the ``gamma / (2*pi*i)`` pseudo-charge factor for the Biot-Savart kernel.
+    ``spill`` holds the particles of boxes fuller than ``s`` (None when no
+    box is: the tree is then the dense grid alone).
     """
 
     z: jax.Array       # complex64 (n, n, s) particle positions
@@ -228,6 +292,7 @@ class Tree:
     mask: jax.Array    # bool      (n, n, s) slot occupancy
     level: int = dataclasses.field(metadata=dict(static=True))
     sigma: float = dataclasses.field(metadata=dict(static=True))
+    spill: Optional[Spill] = None
 
     @property
     def nside(self) -> int:
@@ -239,16 +304,52 @@ class Tree:
 
     @property
     def num_particles(self) -> jax.Array:
-        return self.mask.sum()
+        n = self.mask.sum()
+        return n if self.spill is None else n + self.spill.mask.sum()
+
+    # the spill layout's counters (all 0 for a dense tree)
+
+    @property
+    def spill_particles(self) -> int:
+        return 0 if self.spill is None else self.spill.particles
+
+    @property
+    def spill_chunks(self) -> int:
+        return 0 if self.spill is None else self.spill.chunks
+
+    @property
+    def spill_pairs(self) -> int:
+        return 0 if self.spill is None else self.spill.pairs
+
+    @property
+    def spill_pair_evals(self) -> int:
+        """Pair evaluations of the spill near field: every run's ``run``
+        source chunks, padding included, ``s * s`` each."""
+        if self.spill is None:
+            return 0
+        sp = self.spill
+        return sp.run_tgt.shape[0] * sp.run * self.slots * self.slots
+
+
+def require_dense(tree: Tree, who: str) -> None:
+    """Refuse a tree with spill chunks on a path that reads the dense grid
+    alone."""
+    if tree.spill is not None:
+        raise ValueError(
+            f"{who} takes a dense tree; this one has {tree.spill_chunks} "
+            f"spill chunks ({tree.spill_particles} particles over the "
+            f"capacity of {tree.slots} slots): build it with slots at least "
+            f"the fullest box's occupancy")
 
 
 @dataclasses.dataclass(frozen=True)
 class TreeIndex:
-    """Host-side bookkeeping to map dense tree slots back to input order."""
+    """Host-side bookkeeping to map tree slots back to input order."""
 
     box_of_particle: np.ndarray   # (N,) flat row-major box id per input particle
-    slot_of_particle: np.ndarray  # (N,) slot within the box
+    slot_of_particle: np.ndarray  # (N,) slot within the box, or its chunk
     counts: np.ndarray            # (n, n) particles per box
+    chunk_of_particle: np.ndarray  # (N,) spill chunk, -1 in the dense grid
 
 
 def choose_level(num_particles: int, target_per_box: float = 4.0, max_level: int = 12) -> int:
@@ -257,6 +358,137 @@ def choose_level(num_particles: int, target_per_box: float = 4.0, max_level: int
     while level < max_level and num_particles / float(4 ** (level + 1)) >= target_per_box:
         level += 1
     return level
+
+
+def box_counts(positions: np.ndarray, level: int) -> np.ndarray:
+    """Particles per leaf box, ``(n, n)`` row-major (``[iy, ix]``), binned
+    as :func:`build_tree` bins them: positions outside the unit square
+    count in the edge boxes."""
+    n = 1 << level
+    ij = np.clip((np.asarray(positions) * n).astype(np.int64), 0, n - 1)
+    return np.bincount(ij[:, 1] * n + ij[:, 0],
+                       minlength=n * n).reshape(n, n)
+
+
+# Expected number of boxes, over a whole histogram, that a uniform random
+# field fills past the flat bound of ``choose_slots``.
+FLAT_TAIL = 1e-6
+
+
+def poisson_bound(mean: float, boxes: int, tail: float = FLAT_TAIL) -> int:
+    """The smallest ``c`` such that ``boxes`` independent Poisson counts of
+    mean ``mean`` exceed ``c`` in fewer than ``tail`` boxes on average:
+    ``boxes * P(X > c) <= tail``."""
+    k = np.arange(int(mean + 12.0 * np.sqrt(mean) + 64.0) + 1)
+    logk = np.concatenate([[0.0], np.cumsum(np.log(k[1:]))])   # log k!
+    pmf = np.exp(k * np.log(max(mean, 1e-300)) - mean - logk)
+    above = np.cumsum(pmf[::-1])[::-1] - pmf                    # P(X > k)
+    return int(np.flatnonzero(boxes * above <= tail)[0])
+
+
+def choose_slots(counts: np.ndarray) -> int:
+    """The dense capacity the occupancy histogram ``counts`` calls for.
+
+    Let ``m`` be the mean occupancy of the occupied boxes.  If the fullest
+    box holds at most :func:`poisson_bound` ``(m, occupied boxes)``, the
+    histogram is flat -- a lattice, or a uniform random field, whose
+    occupied boxes are Poisson counts of a mean below ``m``, so that one
+    exceeds that bound in about one histogram of a million -- and the
+    capacity is the fullest box: nothing spills.  Otherwise the tail is a
+    cluster, whose fullest boxes would pad every box to their size; the
+    capacity is the smallest power of two at or above ``m``, and the
+    tail's particles go to spill chunks of that many slots.
+
+    ``build_tree`` does not call this: its default capacity is the fullest
+    box, which every path takes.  A caller that evaluates a clustered
+    field serially passes this pick as ``slots``.
+    """
+    counts = np.asarray(counts)
+    most = int(counts.max()) if counts.size else 0
+    occupied = int((counts > 0).sum())
+    if occupied == 0:
+        return 1
+    mean = counts.sum() / occupied
+    if most <= poisson_bound(mean, occupied):
+        return most
+    return 1 << int(np.ceil(np.log2(max(mean, 1.0))))
+
+
+def _spill_layout(counts: np.ndarray, chunk_box: np.ndarray, n: int
+                  ) -> dict:
+    """The index arrays and static facts of :class:`Spill` beside the
+    particles: the window, the near field's runs and their target groups,
+    and the far field's per-box chunk lists."""
+    nn, K, R = n * n, len(chunk_box), SPILL_RUN
+    nspill = np.bincount(chunk_box, minlength=nn)
+    first = np.zeros(nn + 1, np.int64)
+    np.cumsum(nspill, out=first[1:])
+    occupied = counts.reshape(nn) > 0
+    has = np.flatnonzero(nspill)
+    hy, hx = np.divmod(has, n)
+    y0, x0 = max(hy.min() - 1, 0), max(hx.min() - 1, 0)
+    wy, wx = min(hy.max() + 2, n) - y0, min(hx.max() + 2, n) - x0
+    W = wy * wx
+    wyy, wxx = np.divmod(np.arange(W), wx)
+    wbox = (y0 + wyy) * n + x0 + wxx                  # flat box of each
+    near = np.zeros(W, bool)
+    for dy in (-1, 0, 1):
+        for dx in (-1, 0, 1):
+            yy, xx = y0 + wyy + dy, x0 + wxx + dx
+            ok = (yy >= 0) & (yy < n) & (xx >= 0) & (xx < n)
+            near[ok] |= nspill[yy[ok] * n + xx[ok]] > 0
+    # targets: every spill chunk, and every occupied window box with a
+    # spill chunk among its neighbours
+    dense_t = np.flatnonzero(occupied[wbox] & near)
+    t_id = np.concatenate([W + np.arange(K), dense_t])
+    t_box = np.concatenate([chunk_box, wbox[dense_t]])
+    t_spill = t_id >= W
+    ty, tx = np.divmod(t_box, n)
+    tgt, start, length = [], [], []
+
+    def runs_of(t, c0, c1):         # chunks [c0, c1) of target t, in runs
+        runs = -(-(c1 - c0) // R)
+        k = np.arange(runs.sum()) - np.repeat(np.cumsum(runs) - runs, runs)
+        st = np.repeat(c0, runs) + k * R
+        tgt.append(np.repeat(t, runs))
+        start.append(st)
+        length.append(np.minimum(R, np.repeat(c1, runs) - st))
+
+    for dy in (-1, 0, 1):
+        t = np.flatnonzero((ty + dy >= 0) & (ty + dy < n))
+        lo = np.maximum(tx[t] - 1, 0)
+        hi = np.minimum(tx[t] + 1, n - 1)
+        row = ty[t] + dy
+        # the row's three dense boxes, for a spill target
+        d = t_spill[t]
+        w0 = ((row - y0) * wx + lo - x0)[d]
+        runs_of(t[d], w0, w0 + (hi - lo + 1)[d])
+        # the row's spill chunks
+        runs_of(t, W + first[row * n + lo], W + first[row * n + hi + 1])
+    tgt, start, length = (np.concatenate(a) for a in (tgt, start, length))
+    per = np.bincount(tgt, minlength=len(t_id))
+    order = np.lexsort((np.arange(len(t_id)), per))   # targets, by runs
+    place = np.empty(len(t_id), np.int64)
+    place[order] = np.arange(len(t_id))
+    runs_order = np.argsort(place[tgt], kind="stable")
+    counts_, sizes = np.unique(per[order], return_counts=True)
+    window_pos = np.full(W, len(t_id), np.int64)
+    window_pos[dense_t] = place[K:]
+    cmax = int(nspill.max())
+    box_chunks = np.where(np.arange(cmax)[None] < nspill[has][:, None],
+                          first[has][:, None] + np.arange(cmax)[None], K)
+    window_box = np.full(nn, len(has), np.int64)
+    window_box[has] = np.arange(len(has))
+    i32 = lambda a: jnp.asarray(np.asarray(a, np.int32))
+    return dict(
+        chunk_wbox=i32((chunk_box // n - y0) * wx + chunk_box % n - x0),
+        run_tgt=i32(t_id[tgt][runs_order]),
+        run_start=i32(start[runs_order]), run_len=i32(length[runs_order]),
+        spill_pos=i32(place[:K]), window_pos=i32(window_pos),
+        box_chunks=i32(box_chunks), window_box=i32(window_box[wbox]),
+        window=(int(y0), int(x0), int(wy), int(wx)), run=R,
+        groups=tuple((int(c), int(m)) for c, m in zip(counts_, sizes)),
+        pairs=int(length.sum()))
 
 
 def build_tree(
@@ -268,16 +500,20 @@ def build_tree(
     dtype=np.complex64,
     charge_scale: Optional[complex] = None,
 ) -> tuple[Tree, TreeIndex]:
-    """Bin particles into the dense leaf grid (host-side, NumPy).
+    """Bin particles into the dense leaf grid and its spill chunks
+    (host-side, NumPy).
 
     positions: (N, 2) float in [0, 1)^2;  gamma: (N,) real strengths.
-    ``slots`` pads every box to a fixed capacity (defaults to the max
-    occupancy).  ``charge_scale`` maps the input strength to the stored
-    pseudo-charge ``q`` — the equation spec's ``charge_scale``
-    (core/equations.py); None keeps the vortex default ``1/(2*pi*i)``
-    (circulation -> Biot-Savart pseudo-charge).  This is the TPU-native
-    replacement for the paper's ragged per-box particle lists (see
-    DESIGN.md §3).
+    ``slots`` is the dense capacity of every box (None: the fullest box's
+    occupancy, so that nothing spills).  A box with more particles keeps
+    its first ``slots`` in the dense grid and the rest in spill chunks of
+    ``slots`` (:class:`Spill`); with none, the tree is the dense grid
+    alone.  ``choose_slots`` picks a capacity for a clustered field.
+    ``charge_scale`` maps the input strength to the stored pseudo-charge
+    ``q`` -- the equation spec's ``charge_scale`` (core/equations.py); None
+    keeps the vortex default ``1/(2*pi*i)`` (circulation -> Biot-Savart
+    pseudo-charge).  The dense grid plus spill chunks is the TPU-native
+    form of the paper's ragged per-box particle lists (see DESIGN.md §3).
     """
     positions = np.asarray(positions, dtype=np.float64)
     gamma = np.asarray(gamma, dtype=np.float64)
@@ -289,30 +525,53 @@ def build_tree(
     order = np.argsort(box, kind="stable")
     sorted_box = box[order]
     counts = np.bincount(sorted_box, minlength=n * n)
-    max_occ = int(counts.max()) if counts.size else 0
     if slots is None:
-        slots = max(max_occ, 1)
-    if max_occ > slots:
-        raise ValueError(f"box occupancy {max_occ} exceeds slot capacity {slots}")
+        slots = max(int(counts.max()) if counts.size else 0, 1)
 
     # slot index = rank of the particle within its (sorted) box run
     starts = np.zeros(n * n + 1, dtype=np.int64)
     np.cumsum(counts, out=starts[1:])
-    slot_sorted = np.arange(len(box)) - starts[sorted_box]
+    rank = np.arange(len(box)) - starts[sorted_box]
+    dense = rank < slots
+    slot_sorted = np.where(dense, rank, (rank - slots) % slots)
 
-    zflat = np.zeros((n * n, slots), dtype=np.complex128)
-    qflat = np.zeros((n * n, slots), dtype=np.complex128)
-    mflat = np.zeros((n * n, slots), dtype=bool)
     if charge_scale is None:
         charge_scale = 1.0 / (2j * np.pi)
     zsrc = positions[order, 0] + 1j * positions[order, 1]
     qsrc = gamma[order] * charge_scale
-    zflat[sorted_box, slot_sorted] = zsrc
-    qflat[sorted_box, slot_sorted] = qsrc
-    mflat[sorted_box, slot_sorted] = True
+
+    def fill(rows, at, nrows):
+        z = np.zeros((nrows, slots), dtype=np.complex128)
+        q = np.zeros((nrows, slots), dtype=np.complex128)
+        m = np.zeros((nrows, slots), dtype=bool)
+        z[rows, slot_sorted[at]] = zsrc[at]
+        q[rows, slot_sorted[at]] = qsrc[at]
+        m[rows, slot_sorted[at]] = True
+        return z, q, m
+
+    zflat, qflat, mflat = fill(sorted_box[dense], dense, n * n)
+
+    chunk_sorted = np.full(len(box), -1, dtype=np.int64)
+    spill = None
+    over = ~dense
+    if over.any():
+        per_box = -(-np.maximum(counts - slots, 0) // slots)
+        chunk_first = np.cumsum(per_box) - per_box
+        chunk_sorted[over] = (chunk_first[sorted_box[over]]
+                              + (rank[over] - slots) // slots)
+        K = int(per_box.sum())
+        chunk_box = np.repeat(np.arange(n * n), per_box)
+        zs, qs, ms = fill(chunk_sorted[over], over, K)
+        spill = Spill(z=jnp.asarray(zs, dtype=dtype),
+                      q=jnp.asarray(qs, dtype=dtype), mask=jnp.asarray(ms),
+                      box=jnp.asarray(chunk_box, dtype=jnp.int32),
+                      particles=int(over.sum()),
+                      **_spill_layout(counts, chunk_box, n))
 
     slot_of_particle = np.empty(len(box), dtype=np.int64)
     slot_of_particle[order] = slot_sorted
+    chunk_of_particle = np.empty(len(box), dtype=np.int64)
+    chunk_of_particle[order] = chunk_sorted
 
     tree = Tree(
         z=jnp.asarray(zflat.reshape(n, n, slots), dtype=dtype),
@@ -320,9 +579,11 @@ def build_tree(
         mask=jnp.asarray(mflat.reshape(n, n, slots)),
         level=level,
         sigma=float(sigma),
+        spill=spill,
     )
     index = TreeIndex(box_of_particle=box, slot_of_particle=slot_of_particle,
-                      counts=counts.reshape(n, n))
+                      counts=counts.reshape(n, n),
+                      chunk_of_particle=chunk_of_particle)
     return tree, index
 
 
@@ -345,6 +606,7 @@ def rebuild_tree(tree: Tree, new_z: jnp.ndarray, aux=None):
     Positions outside the unit square are clamped into the edge boxes,
     matching ``build_tree``'s host binning.
     """
+    require_dense(tree, "rebuild_tree")
     n, s = tree.nside, tree.slots
     N = n * n * s
     z = new_z.reshape(N)
@@ -378,8 +640,20 @@ def rebuild_tree(tree: Tree, new_z: jnp.ndarray, aux=None):
     return new_tree, new_aux, ok
 
 
-def gather_particle_values(values: np.ndarray, index: TreeIndex) -> np.ndarray:
-    """Read per-slot results back into the original particle order."""
+def gather_particle_values(values, index: TreeIndex) -> np.ndarray:
+    """Read per-slot results back into the original particle order.
+
+    ``values`` is the dense grid's ``(n, n, s)`` array, or for a tree with
+    spill chunks the pair ``(dense, spill)`` that ``fmm_evaluate`` returns.
+    """
+    dense, spill = values if isinstance(values, tuple) else (values, None)
     n2 = index.counts.size
-    flat = np.asarray(values).reshape(n2, -1)
-    return flat[index.box_of_particle, index.slot_of_particle]
+    out = np.asarray(dense).reshape(n2, -1)[index.box_of_particle,
+                                            index.slot_of_particle]
+    spilled = index.chunk_of_particle >= 0
+    if spilled.any():
+        flat = np.asarray(spill)
+        flat = flat.reshape(flat.shape[0], -1)
+        out[spilled] = flat[index.chunk_of_particle[spilled],
+                            index.slot_of_particle[spilled]]
+    return out

@@ -52,7 +52,8 @@ from .parallel_fmm import parallel_fmm_p2p_prefetch, parallel_fmm_velocity
 from .plan import (BlockPlan, SlabPlan, assignment_from_plan, autotune_plan,
                    candidate_grids, measured_row_scale, plan_from_counts,
                    plan_loads, plan_stats, replan, uniform_plan)
-from .quadtree import Domain, Tree, build_tree, choose_level, rebuild_tree
+from .quadtree import (Domain, Tree, box_counts, build_tree, choose_level,
+                       rebuild_tree, require_dense)
 
 
 def _velocity(tree, p, mesh, mesh_axis, use_kernels, plan, overlap,
@@ -170,6 +171,7 @@ def rk2_step(tree: Tree, dt, payload=None, *, p: int, mesh=None,
     ``pipeline=False`` traces exactly the pre-§12 program (the escape
     hatch the equivalence tests pin).
     """
+    require_dense(tree, "rk2_step")
     v1 = _velocity(tree, p, mesh, mesh_axis, use_kernels, plan, overlap,
                    with_health=guard, faults=faults, pipeline=pipeline)
     w1, h1 = v1 if guard else (v1, None)
@@ -466,8 +468,7 @@ class VortexStepper:
         level = max(choose_level(len(positions), self.target_per_box),
                     self._min_level())
         n = 1 << level
-        ij = np.clip((positions * n).astype(np.int64), 0, n - 1)
-        occ = np.bincount(ij[:, 1] * n + ij[:, 0], minlength=n * n).max()
+        occ = box_counts(positions, level).max()
         slots = max(int(math.ceil(occ * self.slots_headroom)), 2)
         tree_key = ("tree", array_digest(positions, gamma), level, slots,
                     float(sigma_unit), complex(1.0 / (2j * np.pi)))

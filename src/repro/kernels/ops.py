@@ -113,6 +113,12 @@ def p2p_apply_slab(z_halo, q_halo, mask_halo, sigma,
                                 lane_pad=lane_pad, z_tgt=z_tgt, eq=eq)
 
 
+def p2p_apply_chunks(tgt, src, run_len, sigma, eq=None):
+    """P2P of the spill layout's chunk runs (``p2p.p2p_chunk_pallas``)."""
+    return _p2p.p2p_chunk_pallas(tgt, src, run_len, sigma=sigma,
+                                 interpret=_interpret(), eq=eq)
+
+
 def m2l_apply(me, level: int, p: int, block: tuple[int, int] | None = None,
               lane_pad: bool | None = None, eq=None):
     """Parity-folded M2L for one level's full (ny, nx, p) ME grid."""

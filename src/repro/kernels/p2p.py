@@ -213,3 +213,91 @@ def p2p_pallas(z, q, mask, sigma=None, block: tuple[int, int] = (8, 8),
     return p2p_pallas_slab(jnp.pad(z, pad), jnp.pad(q, pad),
                            jnp.pad(mask, pad), sigma=sigma, block=block,
                            interpret=interpret, lane_pad=lane_pad, eq=eq)
+
+
+# ---------------------------------------------------------------------------
+# Chunk-pair mode: the spill layout's near field (core/quadtree.Spill).
+#
+# Each run is a target chunk of ``s`` slots against up to ``R`` source
+# chunks of ``s`` slots.  The caller gathers each run as one row of f32:
+# targets ``(U, 2s)`` (x, then y) and sources ``(U, R*5s)`` (for each
+# source chunk x, y, Re q, Im q, mask), with ``(U,)`` the number of live
+# source chunks of each run.  The kernel takes ``lanes`` rows a block and
+# transposes them in VMEM, so that the runs lie on the 128 lanes as boxes
+# do above (an XLA transpose would cost a pass over the gathered rows).
+# It then runs over the R source chunks and, within one, over its s
+# slots, so that a run costs exactly ``R * s * s`` pair evaluations,
+# accumulated in f32 registers as the dense kernel's are.
+# ---------------------------------------------------------------------------
+
+CHUNK_LANES = 512        # runs per block of the chunk-pair kernel
+
+
+def _p2p_chunk_kernel(tgt_ref, src_ref, len_ref, out_ref, *, eq,
+                      sigma: float | None, R: int, s: int):
+    t = tgt_ref[...].T                 # (2s, BL) target slots of each run
+    tx, ty = t[:s], t[s:]
+    planes = src_ref[...].T            # (R*5s, BL)
+    live = len_ref[...]                # (1, BL) live source chunks
+
+    def chunk(r, accs):
+        on = live > r
+        for j in range(s):
+            sx, sy, sqr, sqi, sm = (
+                planes[(5 * r + f) * s + j:(5 * r + f) * s + j + 1]
+                for f in range(5))
+            ddx = tx - sx
+            ddy = ty - sy
+            r2 = ddx * ddx + ddy * ddy
+            valid = (sm > 0) & on & (r2 > 0.0)
+            moll = None
+            if sigma is not None:
+                moll = 1.0 - jnp.exp(-r2 / (2.0 * sigma * sigma))
+            terms = eq.p2p_terms(ddx, ddy, r2, valid, sqr, sqi, moll)
+            accs = tuple(a + t for a, t in
+                         zip(accs, (x for pair in terms for x in pair)))
+        return accs
+
+    accs = tuple(jnp.zeros(tx.shape, jnp.float32)
+                 for _ in range(out_ref.shape[0]))
+    for r in range(R):
+        accs = chunk(r, accs)
+    for c, acc in enumerate(accs):
+        out_ref[c] = acc
+
+
+@functools.partial(jax.jit, static_argnames=("sigma", "lanes", "interpret",
+                                             "eq"))
+def p2p_chunk_pallas(tgt, src, run_len, sigma=None, lanes: int = CHUNK_LANES,
+                     interpret: bool | None = None, eq=None):
+    """P2P of chunk runs given as rows: ``tgt`` (U, 2s) target positions,
+    ``src`` (U, R*5s) source positions, charges and occupancy, ``run_len``
+    (U,) the live source chunks of each run; run ``u``'s targets meet the
+    ``run_len[u] * s`` sources of its live chunks.  Returns
+    (2 * eq.nout, s, U) f32: each channel's real and imaginary parts.
+
+    Runs lie on the lanes, ``lanes`` of them a block (a multiple of 128; a
+    launch with fewer runs takes them all in one block; the last block
+    may be partial, its missing runs neither read nor written).  A
+    particle meets itself at ``r2 = 0`` and is left out, as in the dense
+    kernel.
+    """
+    if interpret is None:
+        from .ops import _interpret
+        interpret = _interpret()
+    eq = _eqs.get_equation(eq)
+    U, two_s = tgt.shape
+    s = two_s // 2
+    R = src.shape[1] // (5 * s)
+    BL = lanes if U > lanes else U
+    return pl.pallas_call(
+        functools.partial(_p2p_chunk_kernel, eq=eq, sigma=sigma, R=R, s=s),
+        grid=(pl.cdiv(U, BL),),
+        in_specs=[pl.BlockSpec((BL, 2 * s), lambda i: (i, 0)),
+                  pl.BlockSpec((BL, 5 * R * s), lambda i: (i, 0)),
+                  pl.BlockSpec((1, BL), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((2 * eq.nout, s, BL), lambda i: (0, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((2 * eq.nout, s, U), jnp.float32),
+        interpret=interpret,
+        name="p2p_chunk_kernel",
+    )(tgt, src, run_len.reshape(1, U).astype(jnp.int32))

@@ -16,9 +16,15 @@ Names in use (a reader of the trace depends on them):
   device scopes  ``fmm.p2m``, ``fmm.m2m``, ``fmm.m2l``, ``fmm.l2l``,
                  ``fmm.l2p``, ``fmm.p2p``, ``fmm.assemble``, ``fmm.health``,
                  ``fmm.exchange``; ``stepper.kick``, ``stepper.rebin``,
-                 ``stepper.health``
+                 ``stepper.health``; ``fmm.spill``, innermost under
+                 ``fmm.p2m``, ``fmm.l2p``, ``fmm.p2p`` and
+                 ``fmm.assemble``, the spill chunks' work (a dense tree
+                 traces none)
   host spans     ``fmm:stepper.step``, ``.dispatch``, ``.wait``, ``.pull``,
                  ``.replan``, ``.relevel``, ``.recover``, ``.checkpoint``
+  counters       ``Tree.spill_particles``, ``.spill_chunks``,
+                 ``.spill_pairs``, ``.spill_pair_evals``: static counts of
+                 a tree's spill layout (``core/quadtree.py``)
 """
 from __future__ import annotations
 

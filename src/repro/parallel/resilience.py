@@ -263,8 +263,14 @@ def announce_fault(coord_dir: str, generation: int, dead: Sequence[int],
     path = os.path.join(gen_dir(coord_dir, generation), "fault.json")
     obj = {"dead": sorted(int(r) for r in dead), "epoch": epoch,
            "by": by, "t": time.time()}
-    _write_excl_json(path, obj)
+    if _write_excl_json(path, obj):
+        return obj
+    # the winner holds the lock but may not have published yet
+    deadline = time.monotonic() + 5.0
     got = _read_json(path)
+    while got is None and time.monotonic() < deadline:
+        time.sleep(0.001)
+        got = _read_json(path)
     return got if got is not None else obj
 
 

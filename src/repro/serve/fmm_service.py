@@ -219,9 +219,7 @@ def ensure_device(tree: Tree) -> Tree:
     Numpy leaves key a separate jit cache entry from device arrays of the
     same aval, so client-supplied or checkpoint-restored host buffers would
     silently recompile every entry point on first touch."""
-    return Tree(z=jnp.asarray(tree.z), q=jnp.asarray(tree.q),
-                mask=jnp.asarray(tree.mask), level=tree.level,
-                sigma=tree.sigma)
+    return jax.tree_util.tree_map(jnp.asarray, tree)
 
 
 def stack_trees(trees: list, capacity: int):

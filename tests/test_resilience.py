@@ -249,6 +249,24 @@ def test_concurrent_detection_single_decision(tmp_path):
     assert rz.read_decision(d, 0)["survivors"] == [0, 1]
 
 
+def test_a_detector_that_loses_the_lock_gets_the_winners_record(tmp_path):
+    """The race behind concurrent detection, made to happen: the winner
+    holds ``fault.json``'s lock but publishes its record 0.2 s later; the
+    loser waits for that record instead of returning its own."""
+    d = str(tmp_path)
+    path = os.path.join(rz.gen_dir(d, 0), "fault.json")
+    open(path + ".lock", "w").close()
+    winner = {"dead": [2], "epoch": 7, "by": 0, "t": 0.0}
+    t = threading.Timer(0.2, rz._write_atomic,
+                        args=(path, json.dumps(winner)))
+    t.start()
+    try:
+        got = rz.announce_fault(d, 0, [2], epoch=7, by=1)
+    finally:
+        t.join()
+    assert got == winner
+
+
 def test_divergent_views_converge_by_intersection(tmp_path):
     """One detector still believes a doubly-dead rank is alive; the views
     are intersected and re-voted at epoch+1 until identical."""

@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import obs
 from repro.core import equations as eqs
 from repro.core.fmm import fmm_evaluate
-from repro.core.quadtree import Tree
+from repro.core.quadtree import SPILL_RUN, Tree, build_tree
 from repro.core.stepper import rk2_step
 from repro.kernels import m2l as km
 from repro.kernels import ops
@@ -31,6 +31,9 @@ from repro.kernels import p2p as kp
 
 P = 17                # the paper's expansion order
 EVAL_LEVEL = 10       # the paper's tree, 4 slots per leaf
+# the Plummer cell's spill layout at level 10: dense capacity 8, 703,001
+# runs of its near field
+SPILL_SLOTS, SPILL_RUNS = 8, 703001
 STEP_LEVEL, STEP_SLOTS = 8, 50   # VortexStepper's choice for 765,625 particles
 TILE = 1 << (EVAL_LEVEL - 1)     # leaf tile of the level-10 grid, 2x2 plan
 
@@ -45,6 +48,18 @@ def one_chip():
     except Exception as e:  # noqa: BLE001 — any failure means "no compiler"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """A mesh of the described topology's four chips."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices), ("data",))
 
 
 def _struct(shape, dtype, sharding):
@@ -106,6 +121,43 @@ def test_p2p_kernel_compiles_for_v5e(one_chip, rows, cols, slots):
         assert d[-1] == -(-max(rows, cols) // 128) * 128
 
 
+def test_p2p_chunk_kernel_compiles_for_v5e(one_chip):
+    """The spill layout's chunk-pair kernel at the Plummer cell's shapes:
+    one custom call that takes the gathered rows as they are (no XLA
+    transpose or lane pad before it) and returns the runs on the lanes,
+    the slots on the sublanes, never padded to 128."""
+    width = 5 * SPILL_RUN * SPILL_SLOTS
+    tgt = _struct((SPILL_RUNS, 2 * SPILL_SLOTS), jnp.float32, one_chip)
+    src = _struct((SPILL_RUNS, width), jnp.float32, one_chip)
+    run_len = _struct((SPILL_RUNS,), jnp.int32, one_chip)
+    text = _compiled_text(
+        lambda t, s, n: kp.p2p_chunk_pallas(t, s, n, sigma=1.5e-4,
+                                            interpret=False),
+        tgt, src, run_len)
+    calls = [ln for ln in text.splitlines()
+             if "p2p_chunk_kernel" in ln and " custom-call(" in ln]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    dims = {tuple(map(int, d.split(",")))
+            for d in re.findall(r"[fs]32\[([\d,]+)\]", calls[0])}
+    assert dims == {(2, SPILL_SLOTS, SPILL_RUNS),
+                    (SPILL_RUNS, 2 * SPILL_SLOTS), (SPILL_RUNS, width),
+                    (1, SPILL_RUNS)}
+    assert not re.search(r"\b(pad|transpose)\b", text)
+
+
+def _spill_tree(one_chip, level=5, slots=8):
+    """The shapes of a small clustered tree with spill chunks."""
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    pos = np.clip(rng.normal(0.5, 0.05, (3000, 2)), 0.0, 0.999)
+    tree, _ = build_tree(pos, rng.uniform(0.9, 1.1, 3000), level, 1e-3,
+                         slots=slots)
+    assert tree.spill is not None
+    return jax.tree_util.tree_map(
+        lambda a: _struct(a.shape, a.dtype, one_chip), tree)
+
+
 def test_fmm_velocity_kernel_program_compiles_for_v5e(one_chip, monkeypatch):
     """The whole serial kernel-route program at a small level.  The kernel
     wrappers ask the (CPU) default backend whether to interpret; steer
@@ -158,6 +210,20 @@ def test_kernels_keep_their_names_for_v5e(one_chip, monkeypatch):
     assert named == {"p2p_kernel", "m2l_kernel"}
 
 
+def test_a_spilled_tree_compiles_with_the_chunk_kernel_for_v5e(
+        one_chip, monkeypatch):
+    """A spilled tree's whole kernel-route program: the chunk-pair kernel
+    joins the dense P2P and M2L kernels, its name holding the word the P2P
+    roofline selects by."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    text = fmm_evaluate.lower(_spill_tree(one_chip), P, eq=eqs.VORTEX,
+                              use_kernels=True).compile().as_text()
+    calls = re.findall(r"^\s+(?:ROOT )?%([\w.\-]+) = [^\n]*? custom-call\(",
+                       text, re.M)
+    named = {c.split(".")[0] for c in calls if "p2p" in c or "m2l" in c}
+    assert named == {"p2p_kernel", "p2p_chunk_kernel", "m2l_kernel"}
+
+
 @pytest.mark.parametrize("program", ["fmm_evaluate", "rk2_step"])
 def test_stage_scopes_change_only_metadata_for_v5e(one_chip, monkeypatch,
                                                    program):
@@ -185,3 +251,37 @@ def test_stage_scopes_change_only_metadata_for_v5e(one_chip, monkeypatch,
     bare, bare_text = compiled()
     assert "fmm.p2p/" in shipped_text and "fmm.p2p/" not in bare_text
     assert shipped == bare
+
+
+def test_four_chip_step_runs_its_collectives_in_rk2_step_for_v5e(
+        four_chips, monkeypatch):
+    """The stepper on a 2x2 block plan compiles to one program,
+    ``jit_rk2_step``, holding the evaluations' halo exchanges as
+    asynchronous collective-permutes: where the benchmark's
+    ``rk2_collective_ms`` reader looks for them on the chip."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.core.cost_model import ModelParams
+    from repro.core.plan import block_plan_from_counts
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    level, slots = 5, 16
+    rng = np.random.default_rng(0)
+    tree, index = build_tree(rng.uniform(0.05, 0.95, (6000, 2)),
+                             np.full(6000, 1 / 6000), level, 1e-3,
+                             slots=slots)
+    plan = block_plan_from_counts(
+        index.counts, ModelParams(level=level, cut=2, p=8, slots=slots),
+        (2, 2))
+    rep = NamedSharding(four_chips, PartitionSpec())
+    tree = jax.tree_util.tree_map(lambda a: _struct(a.shape, a.dtype, rep),
+                                  tree)
+    text = rk2_step.lower(tree, 0.005, p=8, mesh=four_chips,
+                          use_kernels=True, plan=plan,
+                          guard=True).compile().as_text()
+    assert re.match(r"HloModule jit_rk2_step\b", text)
+    starts = re.findall(r"^\s+%collective-permute-start(?:\.\d+)? = ",
+                        text, re.M)
+    dones = re.findall(r"^\s+%collective-permute-done(?:\.\d+)? = ",
+                       text, re.M)
+    assert len(starts) == len(dones) > 0
