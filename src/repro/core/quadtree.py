@@ -588,7 +588,7 @@ def build_tree(
 
 
 def rebuild_tree(tree: Tree, new_z: jnp.ndarray, aux=None):
-    """Device-side rebinning: scatter particles into a fresh dense tree.
+    """Device-side rebinning: place particles into a fresh dense tree.
 
     The jit-able counterpart of :func:`build_tree` — a whole advection step
     can run on device with no host round-trip (core/stepper.py).  ``new_z``
@@ -605,38 +605,40 @@ def rebuild_tree(tree: Tree, new_z: jnp.ndarray, aux=None):
 
     Positions outside the unit square are clamped into the edge boxes,
     matching ``build_tree``'s host binning.
+
+    Placement is a read, never a scatter: after a stable sort by box, box
+    ``b``'s particles are the sorted run ``[start[b], start[b] + count[b])``,
+    so slot ``k`` of box ``b`` reads sorted element ``start[b] + k`` when
+    ``k < count[b]`` and is empty otherwise.  Each output plane is then one
+    gather (a TPU runs a scatter one update at a time).  Box ``b`` keeps
+    its first ``s`` particles in input slot order; the surplus is dropped.
     """
     require_dense(tree, "rebuild_tree")
     n, s = tree.nside, tree.slots
-    N = n * n * s
+    nb, N = n * n, n * n * s
     z = new_z.reshape(N)
-    q = tree.q.reshape(N)
-    m = tree.mask.reshape(N)
 
     ix = jnp.clip((z.real * n).astype(jnp.int32), 0, n - 1)
     iy = jnp.clip((z.imag * n).astype(jnp.int32), 0, n - 1)
-    box = jnp.where(m, iy * n + ix, n * n)        # empty slots sort last
+    box = jnp.where(tree.mask.reshape(N), iy * n + ix, nb)  # empty sort last
+    sb, order = jax.lax.sort((box, jnp.arange(N, dtype=jnp.int32)),
+                             num_keys=1, is_stable=True)
 
-    order = jnp.argsort(box)                      # stable in jax
-    sb = box[order]
-    idx = jnp.arange(N, dtype=jnp.int32)
-    is_start = jnp.concatenate([jnp.ones((1,), bool), sb[1:] != sb[:-1]])
-    # slot = rank within the sorted box run (distance to the run's start)
-    slot = idx - jax.lax.cummax(jnp.where(is_start, idx, 0))
-    ok = jnp.all((sb == n * n) | (slot < s))
+    # box b's sorted run is [edges[b], edges[b + 1])
+    edges = jnp.searchsorted(sb, jnp.arange(nb + 1, dtype=jnp.int32))
+    count = jnp.diff(edges)
+    ok = jnp.all(count <= s)
+    k = jnp.arange(s, dtype=jnp.int32)
+    live = k < count[:, None]                         # (nb, s): the new mask
+    idx = order[jnp.where(live, edges[:-1, None] + k, 0)]
 
-    keep = (sb < n * n) & (slot < s)        # overflow slots are dropped
-    dest = jnp.where(keep, sb * s + slot, N)
+    def place(vals):
+        got = vals.reshape(N)[idx]
+        return jnp.where(live, got, jnp.zeros((), got.dtype)).reshape(n, n, s)
 
-    def scatter(vals, fill=0):
-        flat = jnp.full((N,), fill, dtype=vals.dtype)
-        return flat.at[dest].set(vals.reshape(N)[order], mode="drop") \
-                   .reshape(n, n, s)
-
-    new_tree = Tree(z=scatter(z), q=scatter(q),
-                    mask=scatter(m.astype(jnp.bool_)),
+    new_tree = Tree(z=place(z), q=place(tree.q), mask=live.reshape(n, n, s),
                     level=tree.level, sigma=tree.sigma)
-    new_aux = jax.tree_util.tree_map(scatter, aux) if aux is not None else None
+    new_aux = jax.tree_util.tree_map(place, aux) if aux is not None else None
     return new_tree, new_aux, ok
 
 

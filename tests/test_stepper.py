@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.fmm import fmm_velocity
-from repro.core.quadtree import (build_tree, gather_particle_values,
+from repro.core.quadtree import (Tree, build_tree, gather_particle_values,
                                  rebuild_tree)
 from repro.core.stepper import VortexStepper, rk2_step
 from repro.core.vortex import lamb_oseen_particles
@@ -79,6 +79,98 @@ def test_rebuild_carries_aux_payload():
     a = np.sort(np.asarray(labels)[np.asarray(tree.mask)])
     b = np.sort(np.asarray(new_labels)[np.asarray(new_tree.mask)])
     assert (a == b).all()
+
+
+def _rebuild_by_scatter(tree, new_z, aux=None):
+    """The rebin as a scatter of each sorted particle to its slot: the
+    formulation ``rebuild_tree`` replaced, kept as its reference."""
+    n, s = tree.nside, tree.slots
+    N = n * n * s
+    z = new_z.reshape(N)
+    m = tree.mask.reshape(N)
+    ix = jnp.clip((z.real * n).astype(jnp.int32), 0, n - 1)
+    iy = jnp.clip((z.imag * n).astype(jnp.int32), 0, n - 1)
+    box = jnp.where(m, iy * n + ix, n * n)
+    order = jnp.argsort(box)
+    sb = box[order]
+    idx = jnp.arange(N, dtype=jnp.int32)
+    is_start = jnp.concatenate([jnp.ones((1,), bool), sb[1:] != sb[:-1]])
+    slot = idx - jax.lax.cummax(jnp.where(is_start, idx, 0))
+    ok = jnp.all((sb == n * n) | (slot < s))
+    dest = jnp.where((sb < n * n) & (slot < s), sb * s + slot, N)
+
+    def scatter(vals):
+        flat = jnp.zeros((N,), dtype=vals.dtype)
+        return flat.at[dest].set(vals.reshape(N)[order], mode="drop") \
+                   .reshape(n, n, s)
+
+    new_tree = Tree(z=scatter(z), q=scatter(tree.q), mask=scatter(m),
+                    level=tree.level, sigma=tree.sigma)
+    new_aux = jax.tree_util.tree_map(scatter, aux) if aux is not None else None
+    return new_tree, new_aux, ok
+
+
+# (level, slots, occupied share of slots, centre and spread of the new
+# positions, whether some box must overflow); a centre of None keeps every
+# particle in its box, so each box of a full grid holds exactly ``slots``
+_REBIN_CASES = {
+    "sparse": (4, 8, 0.2, 0.5, 0.25, False),
+    "full_boxes": (3, 8, 1.0, None, 0.0, False),
+    "crowded": (3, 16, 0.9, 0.5, 0.2, None),
+    "overflowing": (5, 4, 0.5, 0.5, 0.04, True),
+    "out_of_domain": (2, 32, 0.7, 0.5, 0.9, None),
+    "deep": (6, 12, 0.3, 0.3, 0.1, True),
+    "no_live_particle": (3, 8, 0.0, 0.5, 0.3, False),
+}
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.dtype, a.shape, a.tobytes()
+
+
+@pytest.mark.parametrize("with_aux", [False, True], ids=["bare", "aux"])
+@pytest.mark.parametrize("case", list(_REBIN_CASES))
+def test_rebuild_matches_scatter_reference_bit_for_bit(case, with_aux):
+    """The inverse-map placement returns what the per-particle scatter
+    wrote, bit for bit: the same first ``s`` particles of each box in the
+    same slots, the same zero fill, the same ``ok`` under overflow, and
+    out-of-domain positions clamped into the same edge boxes."""
+    level, slots, fill, centre, spread, overflows = _REBIN_CASES[case]
+    n = 1 << level
+    shape = (n, n, slots)
+    rng = np.random.default_rng(level * 100 + slots)
+
+    def cplx(loc, scale):
+        return (rng.normal(loc, scale, shape)
+                + 1j * rng.normal(loc, scale, shape)).astype(np.complex64)
+
+    mask = rng.random(shape) < fill
+    tree = Tree(z=jnp.asarray(cplx(0.5, 0.3)), q=jnp.asarray(cplx(0.0, 1.0)),
+                mask=jnp.asarray(mask), level=level, sigma=0.01)
+    # empty slots hold positions too: the rebin must ignore them
+    if centre is None:
+        at = (np.arange(n) + 0.5) / n
+        new_z = jnp.broadcast_to((at[None, :] + 1j * at[:, None])[..., None],
+                                 shape).astype(jnp.complex64)
+    else:
+        new_z = jnp.asarray(cplx(centre, spread))
+    aux = None
+    if with_aux:
+        aux = {"z0": tree.z,
+               "label": jnp.asarray(rng.integers(-9, 9, shape, np.int32)),
+               "flag": (jnp.asarray(rng.random(shape) < 0.5),)}
+
+    got = jax.jit(rebuild_tree)(tree, new_z, aux)
+    want = jax.jit(_rebuild_by_scatter)(tree, new_z, aux)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert _bits(a) == _bits(b)
+    assert got[0].level == level and got[0].sigma == tree.sigma
+    if overflows is not None:
+        assert bool(got[2]) is not overflows
+    if case == "no_live_particle":
+        assert not np.asarray(got[0].mask).any()
 
 
 # ---------------------------------------------------------------------------

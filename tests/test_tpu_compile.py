@@ -36,6 +36,9 @@ EVAL_LEVEL = 10       # the paper's tree, 4 slots per leaf
 SPILL_SLOTS, SPILL_RUNS = 8, 703001
 STEP_LEVEL, STEP_SLOTS = 8, 50   # VortexStepper's choice for 765,625 particles
 TILE = 1 << (EVAL_LEVEL - 1)     # leaf tile of the level-10 grid, 2x2 plan
+# collective-permutes of the four-chip step below: all the evaluations'
+# halo exchanges; the rebin runs on replicated arrays and adds none
+STEP_4CHIP_PERMUTES = 56
 
 
 @pytest.fixture(scope="module")
@@ -253,6 +256,32 @@ def test_stage_scopes_change_only_metadata_for_v5e(one_chip, monkeypatch,
     assert shipped == bare
 
 
+def _opcode(line):
+    """The opcode of one HLO instruction line and the text of its result
+    type, or None for a line that is no instruction."""
+    m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?[\]})]) ([a-z][\w\-]*)\(", line)
+    return (m.group(2), m.group(1)) if m else None
+
+
+def test_rebin_places_particles_without_a_scatter_for_v5e(one_chip,
+                                                           monkeypatch):
+    """The stepper's rebin at level 8 with 50 slots compiles to sorts and
+    gathers alone: no instruction under ``stepper.rebin``, fused or not,
+    is a scatter (which the chip runs one update at a time), nor a sort
+    carrying a ``pred`` operand (what a scattered mask compiles to)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    tree = _small_tree(one_chip, level=STEP_LEVEL, slots=STEP_SLOTS)
+    text = rk2_step.lower(tree, 0.005, p=P, use_kernels=True,
+                          guard=True).compile().as_text()
+    rebin = [_opcode(ln) for ln in text.splitlines()
+             if "/stepper.rebin/" in ln]
+    rebin = [op for op in rebin if op is not None]
+    ops_seen = {op for op, _ in rebin}
+    assert {"sort", "gather"} <= ops_seen
+    assert "scatter" not in ops_seen
+    assert not [t for op, t in rebin if op == "sort" and "pred[" in t]
+
+
 def test_four_chip_step_runs_its_collectives_in_rk2_step_for_v5e(
         four_chips, monkeypatch):
     """The stepper on a 2x2 block plan compiles to one program,
@@ -284,4 +313,4 @@ def test_four_chip_step_runs_its_collectives_in_rk2_step_for_v5e(
                         text, re.M)
     dones = re.findall(r"^\s+%collective-permute-done(?:\.\d+)? = ",
                        text, re.M)
-    assert len(starts) == len(dones) > 0
+    assert len(starts) == len(dones) == STEP_4CHIP_PERMUTES
